@@ -12,7 +12,6 @@ from __future__ import annotations
 import numpy as np
 
 from benchmarks.common import emit, time_fn
-from repro.core.target import CPU_TEST
 from repro.engine import BatchExecutor, qaoa_template
 
 N_QUBITS = 12
@@ -23,7 +22,7 @@ BATCHES = (1, 4, 16, 64)
 def run_backend(backend: str, n: int = N_QUBITS,
                 batches: tuple[int, ...] = BATCHES,
                 verify: bool = False) -> None:
-    ex = BatchExecutor(target=CPU_TEST, backend=backend, verify=verify)
+    ex = BatchExecutor(backend=backend, verify=verify)
     template = qaoa_template(n, LAYERS)
     plan = ex.plan_for(template)
     rng = np.random.default_rng(0)

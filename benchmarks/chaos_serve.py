@@ -29,7 +29,6 @@ import numpy as np
 
 from benchmarks.common import emit
 from benchmarks.serve_mixed import make_traffic
-from repro.core.target import CPU_TEST
 from repro.engine import (BatchExecutor, BatchScheduler, FaultInjector,
                           PlanCache, RetryPolicy)
 
@@ -45,7 +44,7 @@ def serve(cache: PlanCache, traffic, max_batch: int,
           injector: FaultInjector | None = None,
           retry: RetryPolicy | None = None):
     """Submit all traffic, blocking drain; returns (dt, report, states)."""
-    ex = BatchExecutor(target=CPU_TEST, backend="planar", cache=cache,
+    ex = BatchExecutor(backend="planar", cache=cache,
                        injector=injector)
     sched = BatchScheduler(ex, max_batch=max_batch, inflight=0, retry=retry)
     t0 = time.perf_counter()

@@ -38,7 +38,6 @@ import numpy as np
 
 from benchmarks.common import emit
 from benchmarks.serve_mixed import make_traffic
-from repro.core.target import CPU_TEST
 from repro.engine import (BatchExecutor, BatchScheduler, IngestServer,
                           PlanCache)
 from repro.testing import run_producers
@@ -59,7 +58,7 @@ def serve_serialized(cache: PlanCache, traffic):
     """Serialized sync submission: a blocking client.  Each request waits
     for its result before the next is submitted — no cross-request
     batching, the no-front-end baseline."""
-    ex = BatchExecutor(target=CPU_TEST, backend="planar", cache=cache)
+    ex = BatchExecutor(backend="planar", cache=cache)
     sched = BatchScheduler(ex, max_batch=1, inflight=0)
     reqs = []
     t0 = time.perf_counter()
@@ -75,7 +74,7 @@ def serve_serialized(cache: PlanCache, traffic):
 def serve_offline(cache: PlanCache, traffic, max_batch: int):
     """Offline sync lower bound: all requests known up front, one thread,
     blocking batch-by-batch drain."""
-    ex = BatchExecutor(target=CPU_TEST, backend="planar", cache=cache)
+    ex = BatchExecutor(backend="planar", cache=cache)
     sched = BatchScheduler(ex, max_batch=max_batch, inflight=0)
     t0 = time.perf_counter()
     reqs = [sched.submit(t, p) for t, p in traffic]
@@ -89,7 +88,7 @@ def serve_offline(cache: PlanCache, traffic, max_batch: int):
 def serve_ingest(cache: PlanCache, traffic, max_batch: int, clients: int,
                  inflight: int = 2):
     """K concurrent producers through the ingest front end."""
-    ex = BatchExecutor(target=CPU_TEST, backend="planar", cache=cache)
+    ex = BatchExecutor(backend="planar", cache=cache)
     srv = IngestServer(ex, max_batch=max_batch, inflight=inflight,
                        max_wait_ms=MAX_WAIT_MS)
     chunks = [traffic[i::clients] for i in range(clients)]

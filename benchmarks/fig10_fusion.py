@@ -4,7 +4,6 @@ from __future__ import annotations
 from benchmarks.common import emit, time_fn
 from repro.core import circuits as C
 from repro.core.simulator import Simulator
-from repro.core.target import CPU_TEST
 
 
 def run(n: int = 13, fs=(2, 3, 4, 5)):
@@ -13,7 +12,7 @@ def run(n: int = 13, fs=(2, 3, 4, 5)):
         circ = C.build(name, n, **kw)
         best = None
         for f in fs:
-            sim = Simulator(CPU_TEST, backend="planar", f=f)
+            sim = Simulator(backend="planar", f=f)
             fused = sim.prepare(circ)
             t = time_fn(lambda: sim.run(circ).data, iters=2)
             emit(f"fig10/{name}{n}/f{f}", t, f"fused_gates={len(fused)}")

@@ -10,7 +10,6 @@ from __future__ import annotations
 from benchmarks.common import emit, time_fn
 from repro.core import circuits as C
 from repro.core.simulator import Simulator
-from repro.core.target import CPU_TEST
 
 
 def run(n: int = 13):
@@ -18,9 +17,9 @@ def run(n: int = 13):
         kw = {"depth": 6} if name == "qrc" else {}
         circ = C.build(name, n, **kw)
         variants = {
-            "full": Simulator(CPU_TEST, backend="planar"),
-            "no_fusion": Simulator(CPU_TEST, backend="planar", fuse=False),
-            "no_layout": Simulator(CPU_TEST, backend="dense", fuse=False),
+            "full": Simulator(backend="planar"),
+            "no_fusion": Simulator(backend="planar", fuse=False),
+            "no_layout": Simulator(backend="dense", fuse=False),
         }
         times = {}
         for vname, sim in variants.items():

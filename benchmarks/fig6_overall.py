@@ -12,15 +12,14 @@ from __future__ import annotations
 from benchmarks.common import emit, time_fn
 from repro.core import circuits as C
 from repro.core.simulator import Simulator
-from repro.core.target import CPU_TEST
 
 
 def run(n: int = 16):
     for name in ("qft", "ghz", "grover", "qrc", "qv"):
         kw = {"depth": 8} if name == "qrc" else {}
         circ = C.build(name, n, **kw)
-        base = Simulator(CPU_TEST, backend="dense", fuse=False)
-        vla = Simulator(CPU_TEST, backend="planar")
+        base = Simulator(backend="dense", fuse=False)
+        vla = Simulator(backend="planar")
 
         t_base = time_fn(lambda: base.run(circ).data, iters=2)
         t_vla = time_fn(lambda: vla.run(circ).data, iters=2)

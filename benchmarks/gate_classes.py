@@ -26,7 +26,6 @@ import numpy as np
 from benchmarks.common import emit, time_fn
 from repro.core import circuits as C
 from repro.core import gates as G
-from repro.core.target import CPU_TEST
 from repro.engine import BatchExecutor, PlanCache, template_of
 from repro.engine.template import CircuitTemplate, TemplateOp, fixed_op
 
@@ -73,7 +72,7 @@ def run_workload(name: str, template: CircuitTemplate, backend: str,
                      (batch, template.num_params)).astype(np.float32)
     secs: dict[bool, float] = {}
     for spec in specialize_modes:
-        ex = BatchExecutor(target=CPU_TEST, backend=backend, specialize=spec,
+        ex = BatchExecutor(backend=backend, specialize=spec,
                            cache=PlanCache(), verify=verify)
         plan = ex.plan_for(template)
         secs[spec] = time_fn(plan.run_batch_raw, pm, iters=iters) / batch

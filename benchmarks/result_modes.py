@@ -29,7 +29,6 @@ import numpy as np
 from benchmarks.common import emit
 from repro.core import apply as A
 from repro.core import gates as G
-from repro.core.target import CPU_TEST
 from repro.engine import (BatchExecutor, BatchScheduler, PlanCache,
                           ResultSpec, qaoa_template)
 
@@ -49,7 +48,7 @@ def _params_list(template, requests: int, seed: int = 0):
 def _serve(cache: PlanCache, template, params_list, spec, max_batch: int,
            verify: bool = False):
     """One scheduler pass on a warm cache; returns (wall s, results)."""
-    ex = BatchExecutor(target=CPU_TEST, backend="planar", cache=cache,
+    ex = BatchExecutor(backend="planar", cache=cache,
                       verify=verify)
     sched = BatchScheduler(ex, max_batch=max_batch)
     t0 = time.perf_counter()

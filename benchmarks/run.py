@@ -11,6 +11,8 @@ import sys
 import time
 import traceback
 
+from repro.launch.compile_cache import enable_compile_cache
+
 from benchmarks import (batch_throughput, chaos_serve, concurrent_ingest,
                         fig6_overall, fig10_fusion, fig11_ai, fig12_ablation,
                         fig13_scaling, fig14_projection, gate_classes,
@@ -42,6 +44,7 @@ MODULES = {
 
 def main() -> int:
     which = sys.argv[1:] or list(MODULES)
+    enable_compile_cache()
     print("name,us_per_call,derived")
     failed = []
     for name in which:

@@ -18,7 +18,6 @@ import time
 import numpy as np
 
 from benchmarks.common import emit
-from repro.core.target import CPU_TEST
 from repro.engine import (BatchExecutor, BatchScheduler, PlanCache,
                           hea_template, qaoa_template)
 
@@ -42,7 +41,7 @@ def make_traffic(n: int, requests: int, seed: int = 0):
 def serve_once(cache: PlanCache, traffic, mode: str, max_batch: int,
                inflight: int) -> tuple[float, dict]:
     """One pass of the traffic through a fresh scheduler on a warm cache."""
-    ex = BatchExecutor(target=CPU_TEST, backend="planar", cache=cache)
+    ex = BatchExecutor(backend="planar", cache=cache)
     sched = BatchScheduler(ex, max_batch=max_batch,
                            inflight=inflight if mode == "async" else 0)
     t0 = time.perf_counter()

@@ -25,7 +25,6 @@ import numpy as np
 
 from benchmarks.common import emit
 from repro.core import gates as G
-from repro.core.target import CPU_TEST
 from repro.engine import BatchExecutor, BatchScheduler, PlanCache
 from repro.engine.template import CircuitTemplate, TemplateOp, fixed_op
 
@@ -66,7 +65,7 @@ def make_traffic(n: int, requests: int, templates: int, seed: int = 0):
 def serve_once(cache: PlanCache, traffic, routed: bool, max_batch: int,
                verify: bool = False):
     """One streaming pass on a warm cache; returns (dt, report, payloads)."""
-    ex = BatchExecutor(target=CPU_TEST, backend="planar", cache=cache,
+    ex = BatchExecutor(backend="planar", cache=cache,
                        verify=verify)
     sched = BatchScheduler(ex, max_batch=max_batch, max_wait_ms=MAX_WAIT_MS,
                            class_routing=routed)

@@ -1,8 +1,11 @@
 """Sharded batch execution: single-device vs 2/4/8-way device meshes.
 
-Each device count runs in its own subprocess (XLA's host device count must
-be forced before jax initializes), pushing QAOA and Grover batches through
-``BatchExecutor(mesh=D)`` in two layouts:
+On a chip every device count runs in this process over ``jax.devices()``
+(a child could not get a chip this process holds).  On the CPU each count
+runs in a child process with that many forced host devices (XLA's host
+device count must be forced before jax initializes; a CPU backend holds no
+device, so the parent's own JAX use does not get in the way).  Each pushes
+QAOA and Grover batches through ``BatchExecutor(mesh=D)`` in two layouts:
 
 * ``batch``  — the default batch-first policy: whole states stay local,
   the parameter sweep splits over the mesh (embarrassingly parallel).
@@ -41,7 +44,6 @@ def _inner(devices: int, qubits: list[int], batch: int, iters: int,
 
     from benchmarks.common import emit, time_fn
     from repro.core import circuits as C
-    from repro.core.target import CPU_TEST
     from repro.engine import BatchExecutor, PlanCache, qaoa_template, \
         template_of
 
@@ -61,7 +63,7 @@ def _inner(devices: int, qubits: list[int], batch: int, iters: int,
                 plan = run()
                 return time_fn(lambda: run(), iters=iters) / batch, plan
 
-            base_s, _ = bench(BatchExecutor(target=CPU_TEST, backend="planar",
+            base_s, _ = bench(BatchExecutor(backend="planar",
                                             cache=PlanCache(),
                                             verify=verify))
             layouts = [("batch", None)]
@@ -71,7 +73,7 @@ def _inner(devices: int, qubits: list[int], batch: int, iters: int,
                 if devices == 1 and layout == "batch":
                     secs, plan = base_s, None
                 else:
-                    ex = BatchExecutor(target=CPU_TEST, backend="planar",
+                    ex = BatchExecutor(backend="planar",
                                        cache=PlanCache(), mesh=devices,
                                        max_local_qubits=max_local,
                                        verify=verify)
@@ -87,7 +89,14 @@ def _inner(devices: int, qubits: list[int], batch: int, iters: int,
 
 def main(qubits=N_QUBITS, devices=DEVICES, batch: int = BATCH,
          iters: int = ITERS, verify: bool = False) -> None:
-    """Spawn one subprocess per device count and stream its CSV rows."""
+    """Run each device count: in-process on a chip, else one child per
+    count with forced host devices, streaming its CSV rows."""
+    import jax
+    if jax.devices()[0].platform != "cpu":
+        for d in devices:
+            if d <= len(jax.devices()):
+                _inner(d, list(qubits), batch, iters, verify=verify)
+        return
     src = os.path.join(os.path.dirname(__file__), "..", "src")
     root = os.path.join(os.path.dirname(__file__), "..")
     for d in devices:

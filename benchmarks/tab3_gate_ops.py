@@ -8,7 +8,6 @@ from __future__ import annotations
 
 from benchmarks.common import emit
 from repro.core import circuits as C
-from repro.core.target import CPU_TEST
 
 
 def run(n: int = 12, num_vals: int = 8):

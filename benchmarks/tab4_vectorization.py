@@ -15,7 +15,7 @@ from repro.core import circuits as C
 from repro.core import metrics as MET
 from repro.core import statevec as SV
 from repro.core.simulator import Simulator
-from repro.core.target import CPU_TEST, TPU_V5E
+from repro.core.target import TPU_V5E, device_target
 
 
 def run(n: int = 12):
@@ -34,7 +34,7 @@ def run(n: int = 12):
              f"fused={len(fused)}/{circ.num_gates}")
 
     # measured AI of one fused-gate application (XLA cost analysis)
-    st = SV.random_state(n, CPU_TEST, seed=0)
+    st = SV.random_state(n, device_target(), seed=0)
     g = sim.prepare(C.qft(n))[0]
     ur, ui = A.gate_arrays(g)
     ai = MET.measured_ai(

@@ -26,7 +26,6 @@ import time
 
 from benchmarks.common import emit
 from benchmarks.serve_mixed import make_traffic
-from repro.core.target import CPU_TEST
 from repro.engine import (BatchExecutor, IngestServer, PlanCache, SpanTracer,
                           engine_registry)
 from repro.testing import run_producers
@@ -44,7 +43,7 @@ MAX_WAIT_MS = None
 def serve(cache: PlanCache, traffic, max_batch: int, clients: int,
           tracer: SpanTracer | None = None):
     """One ingest burst; returns (wall seconds, report, server)."""
-    ex = BatchExecutor(target=CPU_TEST, backend="planar", cache=cache)
+    ex = BatchExecutor(backend="planar", cache=cache)
     srv = IngestServer(ex, max_batch=max_batch, inflight=2,
                        max_wait_ms=MAX_WAIT_MS, tracer=tracer)
     chunks = [traffic[i::clients] for i in range(clients)]

@@ -17,7 +17,7 @@ import numpy as np  # noqa: E402
 from repro.core import circuits as C  # noqa: E402
 from repro.core.distributed import DistributedSimulator  # noqa: E402
 from repro.core.simulator import Simulator  # noqa: E402
-from repro.core.target import CPU_TEST  # noqa: E402
+from repro.core.target import device_target  # noqa: E402
 
 
 def main():
@@ -25,10 +25,10 @@ def main():
     mesh = jax.make_mesh((2, 4), ("data", "model"),
                          axis_types=(jax.sharding.AxisType.Auto,) * 2)
     circ = C.qft(n)
-    ds = DistributedSimulator(n, mesh, CPU_TEST, f=4)
+    ds = DistributedSimulator(n, mesh, device_target(), f=4)
     out, perm, counters = ds.run(circ)
     psi = np.asarray(ds.to_dense(out, perm))
-    ref = np.asarray(Simulator(CPU_TEST, backend="dense").run(circ)
+    ref = np.asarray(Simulator(backend="dense").run(circ)
                      .to_dense())
     err = np.abs(psi - ref).max()
     print(f"QFT({n}) on {mesh.devices.size} devices: "

@@ -12,7 +12,7 @@ from repro.core.target import CPU_TEST, TPU_V5E
 
 def main():
     # 1. GHZ: maximally entangled state, checked analytically
-    sim = Simulator(CPU_TEST, backend="planar")
+    sim = Simulator(backend="planar")
     state = sim.run(C.ghz(10))
     probs = np.asarray(sim.probabilities(state))
     print(f"GHZ(10): P(|0..0>)={probs[0]:.3f}  P(|1..1>)={probs[-1]:.3f}")
@@ -20,8 +20,8 @@ def main():
 
     # 2. Grover: amplify a marked item
     circ = C.grover(8, marked=123, iterations=3)
-    state = Simulator(CPU_TEST, backend="planar").run(circ)
-    probs = np.asarray(Simulator(CPU_TEST).probabilities(state))
+    state = Simulator(backend="planar").run(circ)
+    probs = np.asarray(Simulator().probabilities(state))
     print(f"Grover(8): argmax={probs.argmax()} (marked=123), "
           f"P={probs[123]:.3f}")
     assert probs.argmax() == 123
@@ -37,8 +37,8 @@ def main():
               f"({s['reduction']:.1f}x fewer state sweeps)")
 
     # 4. Pallas kernel backend (interpret mode on CPU, compiled on TPU)
-    state_k = Simulator(CPU_TEST, backend="pallas", f=3).run(C.qft(8))
-    state_r = Simulator(CPU_TEST, backend="dense").run(C.qft(8))
+    state_k = Simulator(backend="pallas", f=3).run(C.qft(8))
+    state_r = Simulator(backend="dense").run(C.qft(8))
     err = np.abs(np.asarray(state_k.to_dense())
                  - np.asarray(state_r.to_dense())).max()
     print(f"Pallas kernel vs dense oracle: max |diff| = {err:.2e}")

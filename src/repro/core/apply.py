@@ -5,18 +5,30 @@ Two implementations live here:
 * ``apply_gate_dense`` — the *naive baseline*: operates on the dense
   ``complex64[2**n]`` vector (XLA's complex storage is interleaved re/im,
   which is exactly the layout the paper shows defeats auto-vectorization).
-  This is the oracle for everything else and the Fig-6 baseline.
+  This is the oracle for everything else and the Fig-6 baseline.  It is
+  written apart from the planar path on purpose: every view is the flat
+  vector, partners are read by shifting it and selected by index bits, so
+  a fault in the planar views or bit exchanges cannot cancel out.
 
 * ``apply_gate_planar`` — the VLA design in pure JAX on the lane-tiled planar
-  layout ``f32[2, R, V]``: explicit real arithmetic (4 real matmuls per
-  complex matvec, like the paper's FMA formulation), unit-stride lane loads.
+  layout ``f32[2, R, V]``: explicit real arithmetic (4 real products per
+  complex multiply, like the paper's FMA formulation), unit-stride lane loads.
   The Pallas kernels in ``repro.kernels`` implement the same contract with
   explicit VMEM staging; this function is their mid-level reference.
+
+The planar path views the flat amplitude axis with the spans between marked
+bits merged, ``(..., d_hi, 2, d_mid, 2, d_lo)`` (:func:`span_view`), so the
+rank grows with the gate's width and never with ``n``.  A marked bit below
+the lane boundary would make ``d_lo`` narrower than a vector tile, which a
+TPU pads to whole (8, 128) tiles; instead the lane bits are first exchanged
+with a free block of row bits (:func:`lane_window`, :func:`swap_bits` — one
+transpose), so the gate only ever touches row axes.
 
 Conventions: see ``repro.core.gates``.
 """
 from __future__ import annotations
 
+import functools
 from typing import Sequence
 
 import jax
@@ -25,65 +37,191 @@ import numpy as np
 
 from repro.core.gates import Gate
 
+HIGHEST = jax.lax.Precision.HIGHEST
 
-def _apply_on_axes_complex(t: jax.Array, u: jax.Array, axes: Sequence[int]) -> jax.Array:
-    """Apply u (2^k x 2^k, complex) over tensor axes; axes[m] <-> gate bit m."""
-    k = len(axes)
-    order = [axes[m] for m in reversed(range(k))]  # axis for MSB first
-    t = jnp.moveaxis(t, order, range(k))
-    rest = t.shape[k:]
-    t = t.reshape(1 << k, -1)
-    t = u @ t
-    t = t.reshape((2,) * k + rest)
-    return jnp.moveaxis(t, range(k), order)
+# Planar gates up to this width apply as unrolled per-output sums over the
+# 2**k partner slices (one fused elementwise pass); wider gates move their
+# axes to the front and take one matmul.
+_ELEMENTWISE_MAX_K = 3
 
 
-def _apply_on_axes_planar(t: jax.Array, u_re: jax.Array, u_im: jax.Array,
-                          axes: Sequence[int]) -> jax.Array:
-    """Same, on a planes-first real tensor t[2, ...]; axes exclude plane axis."""
-    k = len(axes)
-    order = [axes[m] for m in reversed(range(k))]
-    t = jnp.moveaxis(t, order, range(1, k + 1))
-    rest = t.shape[k + 1:]
-    t = t.reshape(2, 1 << k, -1)
-    re, im = t[0], t[1]
-    # complex matvec as 4 real matmuls (paper's FMA formulation)
-    out_re = u_re @ re - u_im @ im
-    out_im = u_re @ im + u_im @ re
-    t = jnp.stack([out_re, out_im])
-    t = t.reshape((2,) + (2,) * k + rest)
-    return jnp.moveaxis(t, range(1, k + 1), order)
+def span_view(n: int, bits: Sequence[int]) -> tuple[tuple[int, ...], dict]:
+    """``(dims, axis)``: the flat ``2**n`` index (MSB first) with each bit in
+    ``bits`` its own size-2 axis and the spans between them merged; ``axis``
+    maps each bit to its axis in ``dims``."""
+    dims: list[int] = []
+    axis: dict[int, int] = {}
+    prev = n
+    for b in sorted(set(bits), reverse=True):
+        if prev - b - 1 > 0:
+            dims.append(1 << (prev - b - 1))
+        axis[b] = len(dims)
+        dims.append(2)
+        prev = b
+    if prev > 0:
+        dims.append(1 << prev)
+    return tuple(dims), axis
 
 
-def _subtensor_apply(t: jax.Array, n_axes: int, plane_offset: int,
-                     ctrl_axes: list[int], tgt_axes: list[int],
-                     apply_fn) -> jax.Array:
-    """Apply ``apply_fn`` on the subtensor where all control axes == 1."""
-    c = len(ctrl_axes)
-    if c == 0:
-        return apply_fn(t, tgt_axes)
-    dst = list(range(plane_offset, plane_offset + c))
-    t2 = jnp.moveaxis(t, ctrl_axes, dst)
-    idx = (slice(None),) * plane_offset + (1,) * c
-    sub = t2[idx]
-    # axis positions of targets inside the reduced tensor
-    rem = [a for a in range(plane_offset + n_axes) if a not in set(ctrl_axes)]
-    pos = {a: i for i, a in enumerate(rem)}
-    sub_axes = [pos[a] for a in tgt_axes]
-    sub = apply_fn(sub, sub_axes)
-    t2 = t2.at[idx].set(sub)
-    return jnp.moveaxis(t2, dst, ctrl_axes)
+def lane_window(n: int, v: int, bits: Sequence[int]) -> int | None:
+    """Lowest ``s >= v`` such that row bits ``[s, s + v)`` hold none of
+    ``bits``: the block the lane bits are exchanged with.  ``None`` when no
+    gate bit is a lane bit, or when the state has no such free block (small
+    states, where the plain narrow view costs little)."""
+    if v == 0 or all(b >= v for b in bits):
+        return None
+    used = set(bits)
+    for s in range(v, n - v + 1):
+        if used.isdisjoint(range(s, s + v)):
+            return s
+    return None
 
+
+def swap_bits(x: jax.Array, n: int, lo: int, w: int, s: int) -> jax.Array:
+    """Exchange amplitude bits ``[lo, lo + w)`` with ``[s, s + w)``, where
+    ``s >= lo + w`` (an involution: one transpose of two ``2**w`` axes).
+    ``x`` is any array whose trailing axes flatten to the ``2**n``
+    amplitude index; leading axes are kept."""
+    shape = x.shape
+    lead = shape[:-1] if shape[-1] == 1 << n else shape[:-2]
+    t = x.reshape(lead + (1 << (n - s - w), 1 << w, 1 << (s - lo - w),
+                          1 << w, 1 << lo))
+    t = jnp.swapaxes(t, len(lead) + 1, len(lead) + 3)
+    return t.reshape(shape)
+
+
+def _lane_bits_out(x, n: int, v: int, qubits, controls):
+    """Move lane gate bits to a free row block: ``(x, qubits, controls, s)``
+    with the bits renamed; ``s`` is None when nothing moved."""
+    s = lane_window(n, v, tuple(qubits) + tuple(controls))
+    if s is None:
+        return x, tuple(qubits), tuple(controls), None
+    mv = lambda bs: tuple(b + s if b < v else b for b in bs)
+    return swap_bits(x, n, 0, v, s), mv(qubits), mv(controls), s
+
+
+def _partner_slices(t, axis, qubits, lead: int) -> list:
+    """The ``2**k`` sub-tensors of ``t`` at each gate-bit pattern ``i``
+    (bit ``m`` of ``i`` <-> ``qubits[m]``); gate axes kept with size 1."""
+    out = []
+    for i in range(1 << len(qubits)):
+        idx = [slice(None)] * t.ndim
+        for m, q in enumerate(qubits):
+            b = (i >> m) & 1
+            idx[lead + axis[q]] = slice(b, b + 1)
+        out.append(t[tuple(idx)])
+    return out
+
+
+def _assemble(outs: list, axis, qubits, lead: int):
+    """Inverse of :func:`_partner_slices`: concatenate the per-pattern
+    outputs back along their gate axes."""
+    def build(m: int, base: int):
+        if m < 0:
+            return outs[base]
+        ax = lead + axis[qubits[m]]
+        return jnp.concatenate([build(m - 1, base),
+                                build(m - 1, base | (1 << m))], axis=ax)
+    return build(len(qubits) - 1, 0)
+
+
+def _control_mask(dims, axis, controls, lead: int):
+    """Boolean mask, broadcastable over the view, true where every control
+    bit is 1 (None without controls)."""
+    mask = None
+    for c in controls:
+        shp = [1] * (lead + len(dims))
+        shp[lead + axis[c]] = 2
+        m = jax.lax.broadcasted_iota(jnp.int32, tuple(shp), lead + axis[c]) == 1
+        mask = m if mask is None else mask & m
+    return mask
+
+
+# -- dense reference: complex64[2**n] -----------------------------------------
 
 def apply_gate_dense(psi: jax.Array, n: int, qubits: tuple[int, ...],
                      u: jax.Array, controls: tuple[int, ...] = ()) -> jax.Array:
-    """Naive-baseline gate application on the dense complex vector."""
-    t = psi.reshape((2,) * n)
-    axis = lambda q: n - 1 - q
-    t = _subtensor_apply(
-        t, n, 0, [axis(q) for q in controls], [axis(q) for q in qubits],
-        lambda tt, ax: _apply_on_axes_complex(tt, u, ax))
-    return t.reshape(1 << n)
+    """Naive-baseline gate application on the dense complex vector.
+
+    ``out[x] = sum_i u[j, i] psi[x with its gate bits set to i]``, where
+    ``j`` reads the gate bits of ``x`` (amplitudes whose controls are not
+    all 1 are kept).  The partner ``psi[x + pos(i) - pos(j)]`` is the flat
+    vector rolled by a constant for each ``(j, i)``, and ``j`` is selected
+    per amplitude from an index iota: the vector is never reshaped, so no
+    view has a narrow minor axis.  On a TPU each distinct shift is a
+    state-sized temporary, ``3**k`` of them for a ``k``-qubit gate.
+    """
+    return _dense_gate(psi, n, tuple(qubits), jnp.asarray(u, jnp.complex64),
+                       tuple(controls))
+
+
+@functools.partial(jax.jit, static_argnums=(1, 2, 4))
+def _dense_gate(psi, n: int, qubits: tuple[int, ...], u,
+                controls: tuple[int, ...]):
+    psi = psi.reshape(1 << n)
+    x = jax.lax.iota(jnp.int32, 1 << n)
+
+    def bit(q):
+        return (x >> q) & 1
+
+    def pos(i):
+        return sum(((i >> m) & 1) << q for m, q in enumerate(qubits))
+
+    rolled: dict[int, jax.Array] = {}
+
+    def partner(shift):
+        if shift not in rolled:
+            rolled[shift] = jnp.roll(psi, shift)
+        return rolled[shift]
+
+    out = psi
+    for j in range(1 << len(qubits)):
+        y = sum(u[j, i] * partner(pos(j) - pos(i))
+                for i in range(1 << len(qubits)))
+        here = functools.reduce(jnp.logical_and,
+                                [bit(q) == ((j >> m) & 1)
+                                 for m, q in enumerate(qubits)])
+        out = jnp.where(here, y, out)
+    for c in controls:
+        out = jnp.where(bit(c) == 1, out, psi)
+    return out
+
+
+# -- planar design: f32[2, R, V] ----------------------------------------------
+
+def _planar_rows(data, n: int, qubits, u_re, u_im, controls):
+    dims, axis = span_view(n, tuple(qubits) + tuple(controls))
+    t = data.reshape((2,) + dims)
+    k = len(qubits)
+    if k <= _ELEMENTWISE_MAX_K:
+        xs = _partner_slices(t, axis, qubits, 1)
+        outs = []
+        for j in range(1 << k):
+            # out[j] = sum_i u[j, i] x[i] in real arithmetic
+            re = sum(u_re[j, i] * x[0] - u_im[j, i] * x[1]
+                     for i, x in enumerate(xs))
+            im = sum(u_re[j, i] * x[1] + u_im[j, i] * x[0]
+                     for i, x in enumerate(xs))
+            outs.append(jnp.stack([re, im]))
+        out = _assemble(outs, axis, qubits, 1)
+    else:
+        order = [1 + axis[q] for q in reversed(qubits)]
+        s = jnp.moveaxis(t, order, range(1, k + 1))
+        rest = s.shape[k + 1:]
+        # the columns keep their lane axis: (2**k, rows, V) compiles far
+        # faster on a TPU than one (2**k, rows * V) matrix
+        lanes = min(data.shape[-1], int(np.prod(rest)))
+        s = s.reshape(2, 1 << k, -1, lanes)
+        mm = functools.partial(jnp.einsum, "ij,jrl->irl", precision=HIGHEST)
+        # complex matvec as 4 real matmuls (paper's FMA formulation)
+        s = jnp.stack([mm(u_re, s[0]) - mm(u_im, s[1]),
+                       mm(u_re, s[1]) + mm(u_im, s[0])])
+        out = jnp.moveaxis(s.reshape((2,) + (2,) * k + rest),
+                           range(1, k + 1), order)
+    mask = _control_mask(dims, axis, controls, 1)
+    if mask is not None:
+        out = jnp.where(mask, out, t)
+    return out.reshape(data.shape)
 
 
 def apply_gate_planar(data: jax.Array, n: int, qubits: tuple[int, ...],
@@ -91,18 +229,16 @@ def apply_gate_planar(data: jax.Array, n: int, qubits: tuple[int, ...],
                       controls: tuple[int, ...] = ()) -> jax.Array:
     """VLA gate application on the lane-tiled planar layout f32[2, R, V].
 
-    Row qubits and lane qubits are handled uniformly: the (R, V) trailing
-    axes are one contiguous 2**n index space, so exposing a lane qubit is an
-    in-register (sublane/lane) reshuffle after XLA fusion — the predication
-    analogue discussed in DESIGN.md §2.
+    Gate bits in the lane axis are first exchanged with a free block of row
+    bits (a tile transpose), so the gate itself only touches row axes and
+    the lane axis stays whole; the exchange is undone afterwards.
     """
-    shape = data.shape
-    t = data.reshape((2,) + (2,) * n)
-    axis = lambda q: 1 + (n - 1 - q)
-    t = _subtensor_apply(
-        t, n, 1, [axis(q) for q in controls], [axis(q) for q in qubits],
-        lambda tt, ax: _apply_on_axes_planar(tt, u_re, u_im, ax))
-    return t.reshape(shape)
+    v = data.shape[-1].bit_length() - 1
+    data, qubits, controls, s = _lane_bits_out(data, n, v, qubits, controls)
+    out = _planar_rows(data, n, qubits, u_re, u_im, controls)
+    if s is not None:
+        out = swap_bits(out, n, 0, v, s)
+    return out
 
 
 def gate_arrays(g: Gate) -> tuple[jax.Array, jax.Array]:

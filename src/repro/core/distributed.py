@@ -72,13 +72,14 @@ def swap_block(data: jax.Array, axis: str, n_local: int, local_lo: int,
     pre = 1 << (n_local - local_lo - a_bits)
     mid = 1 << a_bits
     post = 1 << local_lo
-    x = data.reshape(-1, pre, mid, post)
+    lanes = min(post, shape[-1])          # keep the lane axis whole
+    x = data.reshape(-1, pre, mid, post // lanes, lanes)
     x = jax.lax.all_to_all(x, axis, split_axis=2, concat_axis=2, tiled=True)
     return x.reshape(shape)
 
 
 def pick_victim(needed: Sequence[int], a_bits: int, top: int,
-                score=None) -> int:
+                score=None, floor: int = 0) -> int:
     """Contiguous ``a_bits``-wide local bit block in ``[0, top)`` avoiding
     every position in ``needed``; with a ``score`` function, the candidate
     whose resident logical qubits are needed furthest in the future wins
@@ -86,7 +87,9 @@ def pick_victim(needed: Sequence[int], a_bits: int, top: int,
 
     Lane bits are legitimate victims too: a device-bit block swapped into
     lane positions simply routes later gates on those logical qubits through
-    the lane path.  Raises ``ValueError`` when no block fits.
+    the lane path.  Blocks at or above ``floor`` are preferred whatever
+    their score (callers keep the vector tile out of the exchange).  Raises
+    ``ValueError`` when no block fits.
     """
     best = None
     for blk in range(top - a_bits, -1, -1):
@@ -94,7 +97,7 @@ def pick_victim(needed: Sequence[int], a_bits: int, top: int,
             continue
         if score is None:
             return blk
-        s = score(blk)
+        s = (blk >= floor, score(blk))
         if best is None or s > best[0]:
             best = (s, blk)
     if best is None:
@@ -408,8 +411,7 @@ class DistributedSimulator:
             final_perm[:] = perm
             return data
 
-        from repro.parallel.sharding import shard_map
-        fn = shard_map(
+        fn = jax.shard_map(
             local_fn, mesh=self.mesh,
             in_specs=(self.spec,) + (P(),) * len(u_planes),
             out_specs=self.spec)

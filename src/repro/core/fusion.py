@@ -22,7 +22,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Sequence
+from typing import Callable, Sequence
 
 import numpy as np
 
@@ -166,6 +166,7 @@ def cluster_gates(gates: Sequence[Gate], f: int,
                   expand_controls_up_to: int = 2,
                   diag_f: int | None = None,
                   classes: Sequence[str | None] | None = None,
+                  allowed: Callable[[tuple[int, ...]], bool] | None = None,
                   ) -> tuple[list[Gate], list[ClusterSpec]]:
     """Greedy vertical + horizontal clustering (Qsim-style) with degree ``f``.
 
@@ -195,6 +196,10 @@ def cluster_gates(gates: Sequence[Gate], f: int,
     Controlled gates whose span exceeds the expansion budget (e.g. Grover's
     multi-controlled Z) stay controlled and act as fusion barriers on their
     qubits.
+
+    ``allowed`` optionally vetoes growing a non-diagonal cluster to a qubit
+    set it rejects (the engine passes the lowering's layout constraint on
+    tiled-memory devices); single gates always form a cluster.
     """
     prep: list[Gate] = []
     clusters: list[_Cluster] = []
@@ -257,6 +262,10 @@ def cluster_gates(gates: Sequence[Gate], f: int,
                     cap = f
                 cand = tuple(sorted(set(c.qubits) | set(g.qubits)))
                 if len(cand) > cap:
+                    continue
+                if (allowed is not None
+                        and _combine_cls(c.cls, cls) != "diagonal"
+                        and not allowed(cand)):
                     continue
                 # all of g's qubits must not be touched by any later cluster
                 if any(last_touch.get(q, -1) > ci for q in touched):

@@ -71,9 +71,10 @@ def expectation_pauli(state: State, paulis: Mapping[int, str]) -> jax.Array:
 
 def marginal_probs(state: State, qubits: Sequence[int]) -> jax.Array:
     """Marginal distribution over ``qubits`` (little-endian order)."""
-    probs = probabilities(state).reshape((2,) * state.n)
-    axes = tuple(state.n - 1 - q for q in range(state.n)
-                 if q not in set(qubits))
+    dims, axis = A.span_view(state.n, qubits)
+    probs = probabilities(state).reshape(dims)
+    kept = {axis[q] for q in qubits}
+    axes = tuple(i for i in range(len(dims)) if i not in kept)
     marg = jnp.sum(probs, axis=axes) if axes else probs
     # remaining axes are qubits sorted descending; reorder to `qubits`
     remaining = sorted(qubits, reverse=True)

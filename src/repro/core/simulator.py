@@ -9,6 +9,10 @@ backend:
 * ``backend="pallas"`` — VLA design with explicit Pallas VMEM kernels
   (interpret mode on CPU; compiled on TPU).
 
+The target and the Pallas interpret mode follow the device
+(:func:`repro.core.target.device_target`, :func:`~repro.core.target.
+resolve_interpret`) unless given.
+
 Fusion degree ``f`` defaults to ``choose_f(target)`` — the machine-balance
 adaptation of paper §IV-D.
 """
@@ -24,22 +28,25 @@ from repro.core import statevec as SV
 from repro.core.circuits import Circuit
 from repro.core.fusion import choose_f, fuse_circuit
 from repro.core.gates import Gate
-from repro.core.target import CPU_TEST, Target
+from repro.core.target import Target, device_target, resolve_interpret
 
 
 @dataclasses.dataclass
 class Simulator:
-    target: Target = CPU_TEST
+    target: Target | None = None   # None = the device's (device_target)
     backend: str = "planar"        # dense | planar | pallas
     f: int | None = None           # horizontal fusion degree; None = auto
     fuse: bool = True
-    interpret: bool = True         # Pallas interpret mode (CPU container)
+    interpret: bool | None = None  # Pallas interpret mode; None = platform's
     specialize: bool = True        # gate-class-specialized plan lowering
     plan_cache: object | None = None  # engine.PlanCache; None = shared global
     mesh: object | None = None     # device count | jax Mesh: sharded plan runs
     max_local_qubits: int | None = None  # per-device row budget (spill knob)
 
     def __post_init__(self):
+        if self.target is None:
+            self.target = device_target()
+        self.interpret = resolve_interpret(self.interpret)
         if self.f is None:
             self.f = choose_f(self.target) if self.fuse else 0
         if self.plan_cache is None:

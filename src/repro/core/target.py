@@ -25,6 +25,9 @@ class Target:
     peak_flops_bf16: float     # FLOP/s, matrix units (0 if none)
     mxu_dim: int               # systolic tile (0 if no matrix unit)
     ici_bw: float              # bytes/s per interconnect link (0 = single chip)
+    # device memory holds arrays in (sublanes, lanes) tiles, so a view whose
+    # minor axes are narrower is padded: lowerings keep the tile whole
+    tiled: bool = False
 
     @property
     def machine_balance_f32(self) -> float:
@@ -82,6 +85,7 @@ TPU_V5E = Target(
     peak_flops_bf16=197e12,
     mxu_dim=128,
     ici_bw=50e9,
+    tiled=True,
 )
 
 # TPU v5p-like descriptor (wider HBM): shows the VLA point — same source,
@@ -96,6 +100,7 @@ TPU_V5P = Target(
     peak_flops_bf16=459e12,
     mxu_dim=128,
     ici_bw=100e9,
+    tiled=True,
 )
 
 # Small descriptor for CPU tests: the same kernels lower with an 8-lane tile,
@@ -136,3 +141,41 @@ def get_target(name: str) -> Target:
         return TARGETS[name]
     except KeyError:
         raise KeyError(f"unknown target {name!r}; have {sorted(TARGETS)}") from None
+
+
+# device_kind strings JAX reports for the TPU generations described above
+_TPU_KINDS = {"TPU v5 lite": TPU_V5E}
+
+
+def device_target(device=None) -> Target:
+    """The :class:`Target` of ``device`` (default: ``jax.devices()[0]``).
+
+    The CPU maps to ``CPU_TEST`` and a TPU v5e to ``TPU_V5E``; any other
+    device raises, so that no chip is ever run with another chip's lane
+    tiling and fusion caps.
+    """
+    if device is None:
+        import jax
+        device = jax.devices()[0]
+    if device.platform == "cpu":
+        return CPU_TEST
+    if device.platform == "tpu" and device.device_kind in _TPU_KINDS:
+        return _TPU_KINDS[device.device_kind]
+    raise ValueError(f"no target for device {device.platform!r} "
+                     f"kind {device.device_kind!r}; known TPU kinds: "
+                     f"{sorted(_TPU_KINDS)}")
+
+
+def resolve_interpret(interpret: bool | None = None, device=None) -> bool:
+    """Pallas interpret mode: ``None`` follows the platform (interpreted
+    everywhere but on a TPU); asking to interpret on a TPU is an error."""
+    if interpret is False:
+        return False
+    if device is None:
+        import jax
+        device = jax.devices()[0]
+    on_tpu = device.platform == "tpu"
+    if interpret and on_tpu:
+        raise ValueError("interpret=True on a TPU: Pallas kernels compile "
+                         "there; leave interpret unset")
+    return not on_tpu

@@ -29,7 +29,7 @@ import numpy as np
 from repro.core import distributed as D
 from repro.core import statevec as SV
 from repro.core.circuits import Circuit
-from repro.core.target import CPU_TEST, Target
+from repro.core.target import Target, device_target, resolve_interpret
 from repro.engine.plan import CacheStats, CompiledPlan, PlanCache
 from repro.engine.resilience import SITE_DISPATCH, SITE_FINALIZE
 from repro.engine.telemetry import ServedActivity
@@ -40,11 +40,11 @@ from repro.engine.template import CircuitTemplate, template_of
 class BatchExecutor:
     """Executes batches of parameter bindings against cached plans."""
 
-    target: Target = CPU_TEST
+    target: Target | None = None     # None = the device's (device_target)
     backend: str = "planar"          # dense | planar | pallas
     f: int | None = None             # fusion degree; None = auto
     fuse: bool = True
-    interpret: bool = True           # Pallas interpret mode
+    interpret: bool | None = None    # Pallas interpret mode; None = platform's
     specialize: bool = True          # gate-class-specialized plan lowering
     cache: PlanCache | None = None
     mesh: object | None = None       # device count | jax Mesh | None
@@ -54,6 +54,9 @@ class BatchExecutor:
     breaker: object | None = None    # resilience.PlanBreaker (quarantine)
 
     def __post_init__(self):
+        if self.target is None:
+            self.target = device_target()
+        self.interpret = resolve_interpret(self.interpret)
         if self.cache is None:
             self.cache = PlanCache()
         # served vectorization activity, aggregated per plan key: what lane

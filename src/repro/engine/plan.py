@@ -49,7 +49,7 @@ from repro.core.circuits import Circuit
 from repro.core.fusion import choose_f, cluster_gates, realize_cluster
 from repro.core.gates import (Gate, expand_unitary, gate_class,
                               monomial_decompose)
-from repro.core.target import Target, row_budget
+from repro.core.target import Target, resolve_interpret, row_budget
 from repro.engine.telemetry import Histogram, vectorization_profile
 from repro.engine.template import PARAM_KINDS, CircuitTemplate, TemplateOp
 
@@ -159,6 +159,49 @@ def _phase_broadcast_shapes(qubits: tuple[int, ...], n: int,
     return tuple(dims), tuple(bshape)
 
 
+@functools.lru_cache(maxsize=4096)
+def _diag_layout(qubits: tuple[int, ...], n: int, v: int) -> tuple:
+    """``(dims, tshape, lane_map)`` for a diagonal over sorted ``qubits`` on
+    the lane-tiled planar state (``v`` lane qubits).
+
+    The row index is factorized by :func:`_phase_broadcast_shapes` over the
+    cluster's row bits and the lane axis stays whole (``dims``), so no view
+    has a minor axis narrower than a vector tile.  Cluster bits in the lane
+    axis are folded into the phase table instead: ``lane_map[l]`` is the
+    lane-bit part of the cluster index at lane ``l``, and the table has
+    shape ``tshape`` (one full lane row per row-bit pattern).
+    """
+    lanes = [q for q in qubits if q < v]
+    rows = tuple(q - v for q in qubits if q >= v)
+    rdims, rshape = _phase_broadcast_shapes(rows, n - v)
+    lane_map = None
+    if lanes:
+        ln = np.arange(1 << v)
+        lane_map = sum(((ln >> q) & 1) << m for m, q in enumerate(lanes))
+    return (rdims + (1 << v,), rshape + ((1 << v) if lanes else 1,),
+            lane_map)
+
+
+def _diag_planes(vec, layout):
+    """Shape a ``2**w`` phase plane (numpy or traced; cluster bit ``m`` <->
+    sorted qubit ``m``, so lane bits are the low bits) into the broadcast
+    table of :func:`_diag_layout`."""
+    _, tshape, lane_map = layout
+    if lane_map is None:
+        return vec.reshape(tshape)
+    lanes_w = 1 << (int(lane_map.max()).bit_length())
+    return vec.reshape(-1, lanes_w)[:, lane_map].reshape(tshape)
+
+
+def _apply_phase(data, pr, pi, layout):
+    """Rotate every amplitude of the planar state by the broadcast phase
+    table of :func:`_diag_planes` (6 real flops per amplitude)."""
+    t = data.reshape((2,) + layout[0])
+    re, im = t[0], t[1]
+    return jnp.stack([pr * re - pi * im, pr * im + pi * re]
+                     ).reshape(data.shape)
+
+
 def _member_monomial(g: Gate, full_qubits: tuple[int, ...],
                      ) -> tuple[np.ndarray, np.ndarray]:
     """Lift a diagonal/monomial member gate into cluster space as
@@ -233,7 +276,7 @@ class PlanItem:
                 m2 = _param_matrix(op, params)
                 e = jnp.where(jnp.asarray(mask), m2[(sr, sc)],
                               jnp.zeros((), jnp.complex64))
-            u = e if u is None else e @ u
+            u = e if u is None else jnp.matmul(e, u, precision=A.HIGHEST)
         return u.astype(jnp.complex64)
 
     def _phase_angle(self, params) -> jax.Array | None:
@@ -501,58 +544,62 @@ def _full_perm_map(qubits: tuple[int, ...], n: int,
     return ((idx & ~mask) | scat).astype(np.int32)
 
 
-def _planar_special_step(item: PlanItem, n: int):
-    """Planar program step for a diag/perm item on an ``n``-qubit state.
+def _flip_bits(data, n: int, v: int, qubits: tuple[int, ...]):
+    """X on every qubit in ``qubits``: a reversal of their axes in the
+    span view, no gather.  Lane bits are first exchanged with a free block
+    of row bits (as for dense items), so only row axes are reversed and the
+    lane axis stays whole."""
+    s = A.lane_window(n, v, qubits)
+    if s is not None:
+        data = A.swap_bits(data, n, 0, v, s)
+        qubits = tuple(q + s if q < v else q for q in qubits)
+    dims, axis = A.span_view(n, qubits)
+    out = jnp.flip(data.reshape((2,) + dims),
+                   axis=[1 + axis[q] for q in qubits]).reshape(data.shape)
+    return out if s is None else A.swap_bits(out, n, 0, v, s)
+
+
+def _planar_special_step(item: PlanItem, n: int, v: int):
+    """Planar program step for a diag/perm item on an ``n``-qubit state
+    with ``v`` lane qubits.
 
     Parameterized by ``n`` rather than the plan's qubit count so the sharded
     path can build the same step on the ``n - state_bits``-qubit local block
     a ``shard_map`` device sees (after relabeling the item's cluster bits
     onto physical positions with :func:`_relabel_special_item`).
+    An XOR-mask permutation (X layers, composed bit flips) is an axis
+    reversal (:func:`_flip_bits`); any other permutation is one static take
+    over the flat amplitude axis.
     """
-    dims, bshape = _phase_broadcast_shapes(item.qubits, n)
+    layout = _diag_layout(item.qubits, n, v)
     has_phase = bool(item.phases)
     const_phase = (item.np_phase_vector()
                    if has_phase and not item.has_param_phase else None)
-    # permutation lowering: an XOR-mask permutation (X layers, composed
-    # bit flips) is a vectorized axis reversal — no gather at all;
-    # anything else is one static take over the flat amplitude axis
-    src = flip_dims = flip_axes = None
+    src = flip_qs = None
     if item.perm is not None:
-        w = len(item.qubits)
         mask = int(item.perm[0])
-        if np.array_equal(item.perm,
-                          np.arange(1 << w, dtype=np.int64) ^ mask):
+        if np.array_equal(item.perm, np.arange(len(item.perm)) ^ mask):
             flip_qs = tuple(q for m, q in enumerate(item.qubits)
                             if (mask >> m) & 1)
-            flip_dims, fshape = _phase_broadcast_shapes(flip_qs, n)
-            flip_axes = tuple(i for i, b in enumerate(fshape) if b > 1)
         else:
             src = _full_perm_map(item.qubits, n, item.perm)
-
     if const_phase is not None:
-        pr_np = np.real(const_phase).reshape(bshape).astype(np.float32)
-        pi_np = np.imag(const_phase).reshape(bshape).astype(np.float32)
+        pr_np = _diag_planes(np.real(const_phase).astype(np.float32), layout)
+        pi_np = _diag_planes(np.imag(const_phase).astype(np.float32), layout)
 
     def step(data, params):
-        shape = data.shape
-        flat = data.reshape(2, -1)
-        if flip_axes is not None:
-            flat = jnp.flip(flat.reshape((2,) + flip_dims),
-                            axis=[a + 1 for a in flip_axes]
-                            ).reshape(2, -1)
+        if flip_qs is not None:
+            data = _flip_bits(data, n, v, flip_qs)
         elif src is not None:
-            flat = flat[:, src]
-        if has_phase:
-            if const_phase is not None:
-                pr, pi = jnp.asarray(pr_np), jnp.asarray(pi_np)
-            else:
-                pr_w, pi_w = item.phase_planes(params)
-                pr, pi = pr_w.reshape(bshape), pi_w.reshape(bshape)
-            t = flat.reshape((2,) + dims)
-            re, im = t[0], t[1]
-            flat = jnp.stack([pr * re - pi * im, pr * im + pi * re]
-                             ).reshape(2, -1)
-        return flat.reshape(shape)
+            data = data.reshape(2, -1)[:, src].reshape(data.shape)
+        if not has_phase:
+            return data
+        if const_phase is not None:
+            pr, pi = jnp.asarray(pr_np), jnp.asarray(pi_np)
+        else:
+            pr_w, pi_w = item.phase_planes(params)
+            pr, pi = _diag_planes(pr_w, layout), _diag_planes(pi_w, layout)
+        return _apply_phase(data, pr, pi, layout)
     return step
 
 
@@ -630,7 +677,8 @@ def _compact_rho(needed: Sequence[int], n_local: int) -> tuple[int, ...]:
     return tuple(rho[p] for p in range(n_local))
 
 
-def _sharded_diag_step(item: PlanItem, phys: tuple[int, ...], n_local: int):
+def _sharded_diag_step(item: PlanItem, phys: tuple[int, ...], n_local: int,
+                       v: int):
     """Diagonal item with cluster bits on *global* positions: applied with
     zero communication.
 
@@ -652,7 +700,7 @@ def _sharded_diag_step(item: PlanItem, phys: tuple[int, ...], n_local: int):
     base = np.zeros_like(yl)
     for j, oj in enumerate(order):
         base |= ((yl >> j) & 1) << loc_ms[int(oj)]
-    dims, bshape = _phase_broadcast_shapes(tuple(sorted(loc_phys)), n_local)
+    layout = _diag_layout(tuple(sorted(loc_phys)), n_local, v)
 
     def step(data, params):
         pr_full, pi_full = item.phase_planes(params)
@@ -661,13 +709,9 @@ def _sharded_diag_step(item: PlanItem, phys: tuple[int, ...], n_local: int):
         for m in glob_ms:
             off = off + (((idx >> (phys[m] - n_local)) & 1) << m)
         gidx = jnp.asarray(base) + off
-        pr = jnp.take(pr_full, gidx).reshape(bshape)
-        pi = jnp.take(pi_full, gidx).reshape(bshape)
-        shape = data.shape
-        t = data.reshape((2,) + dims)
-        re, im = t[0], t[1]
-        return jnp.stack([pr * re - pi * im, pr * im + pi * re]
-                         ).reshape(shape)
+        pr = _diag_planes(jnp.take(pr_full, gidx), layout)
+        pi = _diag_planes(jnp.take(pi_full, gidx), layout)
+        return _apply_phase(data, pr, pi, layout)
     return step
 
 
@@ -765,6 +809,16 @@ def _restore_identity(data: jax.Array, perm: list[int], n: int,
                 rho_fix[perm[q]] = q
         data = _apply_local_bit_perm(data, tuple(rho_fix))
     return data, swaps
+
+
+@functools.lru_cache(maxsize=4096)
+def _dense_gate_fn(n: int, qubits: tuple[int, ...],
+                   controls: tuple[int, ...]):
+    """Jitted dense-baseline gate ``(psi, u) -> psi`` for one gate shape
+    (state donated)."""
+    return jax.jit(lambda psi, u: A.apply_gate_dense(psi, n, qubits, u,
+                                                     controls),
+                   donate_argnums=(0,))
 
 
 @dataclasses.dataclass
@@ -896,10 +950,11 @@ class CompiledPlan:
         planar: the ``2**w`` phase planes are broadcast over the state by a
         reshape that merges contiguous qubit runs into whole axes
         (``_phase_broadcast_shapes``) — an elementwise multiply with no
-        gather and no moveaxis — and permutations are a single static
-        ``take`` over the flat amplitude axis.  pallas: the phase rotates
-        one VMEM block in-register (``_diag_kernel``), with the permutation
-        folded into the block's row gather.  The dense backend never builds
+        gather and no moveaxis — XOR-mask permutations are axis reversals,
+        and other permutations a single static ``take`` over the flat
+        amplitude axis.  pallas: a diagonal streams the state once through
+        the phase kernel, a permutation runs as its monomial matrix through
+        the dense kernel (``apply_phase_gate``).  The dense backend never builds
         special items: ``resolve_f`` pins it to f=0, keeping it the
         unspecialized naive baseline / oracle.
         """
@@ -908,7 +963,7 @@ class CompiledPlan:
                 "dense plans are never specialized (resolve_f forces f=0 "
                 "for the naive baseline)")
         if self.backend == "planar":
-            return _planar_special_step(item, self.n)
+            return _planar_special_step(item, self.n, self.target.lane_qubits)
 
         from repro.kernels.apply_gate import ops as K
         n = self.n
@@ -982,16 +1037,26 @@ class CompiledPlan:
 
     # -- execution ------------------------------------------------------------
     def run(self, params=None, initial: SV.State | None = None) -> SV.State:
-        """Execute for one parameter vector; one dispatch of the fused jit."""
+        """Execute for one parameter vector; one dispatch of the fused jit.
+
+        The dense baseline instead dispatches gate by gate
+        (:func:`_dense_gate_fn`, one executable per gate shape, the state
+        donated each time): a whole unfused circuit in one program keeps
+        several complex states alive at once, which does not fit a chip at
+        the sizes the dense reference is checked against.
+        """
+        if self.backend == "dense":
+            psi = self._initial_data(initial)
+            p = self._params_array(params)
+            for item in self._gate_items():
+                psi = _dense_gate_fn(self.n, item.qubits, item.controls)(
+                    psi, item.unitary(p))
+            return self._wrap(psi)
         with self._plock:
             if self._single is None:
-                # donate the state buffer on the planar paths (matches the
-                # old per-gate jits); dense allocates a fresh complex input
-                # anyway
-                donate = () if self.backend == "dense" else (0,)
-                self._single = jax.jit(self._program(), donate_argnums=donate)
+                self._single = jax.jit(self._program(), donate_argnums=(0,))
         data0 = self._initial_data(initial)
-        if initial is not None and self.backend != "dense":
+        if initial is not None:
             data0 = jnp.array(data0)   # don't donate the caller's buffer
         # lint-ok: EL001 _single is write-once under _plock above; this read
         # happens after the build and the reference is never cleared, so the
@@ -1012,8 +1077,8 @@ class CompiledPlan:
                  else self._initial_data(initial))
         key = (int(pm.shape[0]), batched_init)
         with self._plock:
-            fn = self._get_or_build(key, lambda: self._build_batched(
-                data0, pm, batched_init))
+            fn = self._get_or_build(
+                key, lambda: self._build_batched(batched_init))
         return fn(data0, pm)
 
     def _get_or_build(self, key, build: Callable):
@@ -1047,25 +1112,9 @@ class CompiledPlan:
         count = raw.shape[0] if count is None else count
         return [self._wrap(raw[b]) for b in range(count)]
 
-    def _build_batched(self, data0, pm, batched_init: bool):
-        program = self._program()
+    def _build_batched(self, batched_init: bool):
         in_axes = (0 if batched_init else None, 0)
-        vmapped = jax.vmap(program, in_axes=in_axes)
-        try:
-            jax.eval_shape(vmapped, data0, pm)
-            return jax.jit(vmapped)
-        except Exception:
-            # no batching rule (e.g. pallas_call in some modes): fall back to
-            # a sequential scan inside one jitted program — still a single
-            # compile for the whole batch.
-            if batched_init:
-                def seq(d0, ps):
-                    return jax.lax.map(lambda dp: program(dp[0], dp[1]),
-                                       (d0, ps))
-            else:
-                def seq(d0, ps):
-                    return jax.lax.map(lambda p: program(d0, p), ps)
-            return jax.jit(seq)
+        return jax.jit(jax.vmap(self._program(), in_axes=in_axes))
 
     # -- result-mode execution ------------------------------------------------
     def _row_probs(self, data) -> jax.Array:
@@ -1147,7 +1196,7 @@ class CompiledPlan:
                 phi = psi
                 for q, u in us:
                     phi = A.apply_gate_dense(phi, n, (q,), u)
-                return jnp.real(jnp.vdot(psi, phi)).astype(jnp.float32)
+                return jnp.real(jnp.vdot(psi, phi, precision=A.HIGHEST)).astype(jnp.float32)
             return step
         planes = [(q, jnp.asarray(np.real(ME._PAULI[p]).astype(np.float32)),
                    jnp.asarray(np.imag(ME._PAULI[p]).astype(np.float32)))
@@ -1232,23 +1281,11 @@ class CompiledPlan:
         data0 = self._initial_data(initial)
         key = ("result", int(pm.shape[0]))
         with self._plock:
-            fn = self._get_or_build(key, lambda: self._build_batched_result(
-                data0, pm, rk))
+            fn = self._get_or_build(key, self._build_batched_result)
         return fn(data0, pm, rk)
 
-    def _build_batched_result(self, data0, pm, rk):
-        program = self._result_program()
-        vmapped = jax.vmap(program, in_axes=(None, 0, 0))
-        try:
-            jax.eval_shape(vmapped, data0, pm, rk)
-            return jax.jit(vmapped)
-        except Exception:
-            # same fallback as _build_batched: no batching rule (pallas
-            # epilogue kernels in some modes) -> sequential scan in one jit
-            def seq(d0, ps, ks):
-                return jax.lax.map(lambda pk: program(d0, pk[0], pk[1]),
-                                   (ps, ks))
-            return jax.jit(seq)
+    def _build_batched_result(self):
+        return jax.jit(jax.vmap(self._result_program(), in_axes=(None, 0, 0)))
 
     # -- sharded execution ----------------------------------------------------
     def run_sharded_batch_raw(self, params_matrix, mesh) -> jax.Array:
@@ -1303,11 +1340,12 @@ class CompiledPlan:
         global positions apply communication-free via a per-device phase
         slice; dense items apply directly on the physical targets with
         global controls predicated."""
+        v = self.target.lane_qubits
         if item.kind == "diag" and any(p >= n_local for p in phys):
-            return _sharded_diag_step(item, phys, n_local)
+            return _sharded_diag_step(item, phys, n_local, v)
         if item.kind in ("diag", "perm"):
             return _planar_special_step(_relabel_special_item(item, phys),
-                                        n_local)
+                                        n_local, v)
         local_ctrl = tuple(p for p in cphys if p < n_local)
         glob_ctrl = tuple(p for p in cphys if p >= n_local)
         return _sharded_dense_step(item, phys, local_ctrl, glob_ctrl, n_local)
@@ -1320,6 +1358,8 @@ class CompiledPlan:
         local batch block."""
         n, v, s = self.n, self.target.lane_qubits, self.state_bits
         n_local = n - s
+        # swap victims above the (8, V) vector tile where there is room
+        tile_floor = v + 3
         bl = padded_b // int(dict(zip(mesh.axis_names,
                                       mesh.devices.shape))[D.BATCH_AXIS])
         items = self.items
@@ -1350,6 +1390,9 @@ class CompiledPlan:
             data = data.at[:, 0, 0, 0].set(amp0)
             perm = list(range(n))
             swaps = 0
+            # victim blocks of the exchanges so far; None once a local
+            # gather has moved bits (then the general restore runs)
+            swapped = []
             for ii, item in enumerate(items):
                 phys = [perm[q] for q in item.qubits]
                 cphys = [perm[q] for q in item.controls]
@@ -1366,7 +1409,8 @@ class CompiledPlan:
                         def score(blk):
                             return min(next_use(inv[p], ii)
                                        for p in range(blk, blk + s))
-                        return D.pick_victim(needed, s, n_local, score=score)
+                        return D.pick_victim(needed, s, n_local, score=score,
+                                             floor=tile_floor)
 
                     # prefer a victim avoiding local controls too; when
                     # control-heavy items leave no room, displaced controls
@@ -1385,7 +1429,10 @@ class CompiledPlan:
                         needed = [rho[p] if p < n_local else p
                                   for p in needed]
                         tgt = pick(needed)
+                        swapped = None
                     data = D.swap_block(data, D.STATE_AXIS, n_local, tgt, s)
+                    if swapped is not None:
+                        swapped.append(tgt)
                     perm = D.swap_perm(perm, n_local, tgt, s)
                     swaps += 1
                     phys = [perm[q] for q in item.qubits]
@@ -1393,16 +1440,25 @@ class CompiledPlan:
                 step = self._sharded_item_step(item, tuple(phys),
                                                tuple(cphys), n_local)
                 data = jax.vmap(step)(data, pm_local)
-            data, restore_swaps = _restore_identity(data, perm, n, n_local)
+            if swapped is not None:
+                # only block exchanges moved bits: undo them in reverse, with
+                # no local gather (a 2**n_local index map is a constant the
+                # TPU compiler handles very slowly)
+                for tgt in reversed(swapped):
+                    data = D.swap_block(data, D.STATE_AXIS, n_local, tgt, s)
+                restore_swaps = len(swapped)
+            else:
+                data, restore_swaps = _restore_identity(data, perm, n,
+                                                        n_local)
             counter["swaps"] = swaps + restore_swaps
             return data
 
         from jax.sharding import PartitionSpec as P
 
-        from repro.parallel.sharding import shard_map
-        fn = shard_map(local_fn, mesh=mesh,
-                       in_specs=(P(D.BATCH_AXIS, None),),
-                       out_specs=P(D.BATCH_AXIS, None, D.STATE_AXIS, None))
+        fn = jax.shard_map(local_fn, mesh=mesh,
+                           in_specs=(P(D.BATCH_AXIS, None),),
+                           out_specs=P(D.BATCH_AXIS, None, D.STATE_AXIS,
+                                       None))
         return jax.jit(fn), counter
 
 
@@ -1421,6 +1477,23 @@ def _plan_width_budget(target: Target, n: int, state_bits: int) -> int:
     if state_bits:
         budget = max(2, min(budget, n_local - state_bits))
     return budget
+
+
+def _tile_fit(target: Target, backend: str, n: int):
+    """Cluster predicate on a tiled-memory target (``target.tiled``): a
+    non-diagonal cluster must let its lowering keep the vector tile whole —
+    planar moves lane bits to a free block of row bits
+    (:func:`repro.core.apply.lane_window`), Pallas moves lane and sublane
+    bits (:func:`repro.kernels.apply_gate.ops.tile_swaps`).  ``None`` where
+    narrow views cost nothing."""
+    if not target.tiled or backend == "dense":
+        return None
+    v = target.lane_qubits
+    if backend == "pallas":
+        from repro.kernels.apply_gate.ops import tile_swaps
+        return lambda qs: tile_swaps(n, v, qs) is not None
+    return lambda qs: (min(qs) >= v
+                       or A.lane_window(n, v, qs) is not None)
 
 
 def resolve_f(f: int | None, target: Target, n: int, fuse: bool,
@@ -1459,7 +1532,7 @@ def resolve_diag_f(f_eff: int, target: Target, n: int,
 
 def compile_plan(template: CircuitTemplate, *, backend: str, target: Target,
                  f: int | None = None, fuse: bool = True,
-                 interpret: bool = True, specialize: bool = True,
+                 interpret: bool | None = None, specialize: bool = True,
                  state_bits: int = 0, result=None, verify: bool = False,
                  clock: Callable[[], float] = time.perf_counter,
                  ) -> CompiledPlan:
@@ -1492,6 +1565,7 @@ def compile_plan(template: CircuitTemplate, *, backend: str, target: Target,
     import time).
     """
     t0 = clock()
+    interpret = resolve_interpret(interpret)
     dummy = template.bind(np.zeros(template.num_params))
     ops = template.ops
     f_eff = resolve_f(f, target, template.n, fuse, backend,
@@ -1502,8 +1576,9 @@ def compile_plan(template: CircuitTemplate, *, backend: str, target: Target,
                                 state_bits=state_bits) if specialize else None
         classes = ([PARAM_OP_CLASS.get(op.kind) for op in ops]
                    if specialize else None)
-        prep, specs = cluster_gates(dummy.gates, f_eff, diag_f=diag_f,
-                                    classes=classes)
+        prep, specs = cluster_gates(
+            dummy.gates, f_eff, diag_f=diag_f, classes=classes,
+            allowed=_tile_fit(target, backend, template.n - state_bits))
         diag_cap = diag_f if specialize else None
         items = [it for s in specs
                  if (it := _lower_cluster(s, prep, ops,
@@ -1627,7 +1702,7 @@ class PlanCache:
 
     @staticmethod
     def plan_key(template: CircuitTemplate, *, backend: str, target: Target,
-                 f: int | None, fuse: bool, interpret: bool,
+                 f: int | None, fuse: bool, interpret: bool | None,
                  specialize: bool = True, state_bits: int = 0,
                  result=None) -> tuple:
         """Cache key: structure hash + everything that changes the lowering.
@@ -1645,7 +1720,7 @@ class PlanCache:
         f_eff = resolve_f(f, target, template.n, fuse, backend,
                           state_bits=state_bits)
         return (template.structure_key(), backend, target.name, f_eff,
-                interpret and backend == "pallas",
+                resolve_interpret(interpret) and backend == "pallas",
                 bool(specialize and f_eff), state_bits,
                 # structural result component only (mode, shots, observables,
                 # channel constants); the per-request PRNG key and the
@@ -1654,7 +1729,7 @@ class PlanCache:
 
     def get_or_compile(self, template: CircuitTemplate | Circuit, *,
                        backend: str, target: Target, f: int | None = None,
-                       fuse: bool = True, interpret: bool = True,
+                       fuse: bool = True, interpret: bool | None = None,
                        specialize: bool = True,
                        state_bits: int = 0,
                        result=None,

@@ -38,8 +38,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import apply as A
-from repro.engine.plan import (_CHANNEL_SALT, CompiledPlan, _full_perm_map,
-                               _param_matrix, _phase_broadcast_shapes)
+from repro.engine.plan import (_CHANNEL_SALT, CompiledPlan, _apply_phase,
+                               _diag_layout, _diag_planes, _full_perm_map,
+                               _param_matrix)
 
 # Backends a plan may class-route on.  planar is the serving backend whose
 # item lowering is pure jax-traceable arithmetic; pallas bakes static phase
@@ -167,13 +168,13 @@ def class_slot_shapes(key: tuple) -> tuple[tuple[str, tuple[int, ...]], ...]:
     return tuple(out)
 
 
-def _special_class_step(item, n: int, slot0: int):
+def _special_class_step(item, n: int, v: int, slot0: int):
     """Class-program step for a diag/perm item: the exact-path
     :func:`~repro.engine.plan._planar_special_step` with the static phase
     planes / coefficient vectors / perm map read from the per-row ``consts``
     tuple instead of baked in.  Formula variants match the exact path's
     ``phase_planes`` case split bitwise."""
-    dims, bshape = _phase_broadcast_shapes(item.qubits, n)
+    layout = _diag_layout(item.qubits, n, v)
     has_phase = bool(item.phases)
     has_const = item._np_const_phase() is not None
     param_ops = [p[1] for p in item.phases if p[0] == "param"]
@@ -190,12 +191,11 @@ def _special_class_step(item, n: int, slot0: int):
         s += 1
 
     def step(data, params, consts):
-        shape = data.shape
-        flat = data.reshape(2, -1)
         if perm_slot is not None:
             # full-amplitude-space gather: pure data movement, bitwise-equal
             # to the exact path's flip specialization for XOR perms
-            flat = flat[:, consts[perm_slot]]
+            data = data.reshape(2, -1)[:, consts[perm_slot]].reshape(
+                data.shape)
         if has_phase:
             ang = None
             for op, cs in zip(param_ops, coeff_slots):
@@ -210,12 +210,9 @@ def _special_class_step(item, n: int, slot0: int):
                 else:
                     cr, ci = consts[pr_slot], consts[pi_slot]
                     pr, pi = c * cr - sn * ci, c * ci + sn * cr
-            pr, pi = pr.reshape(bshape), pi.reshape(bshape)
-            t = flat.reshape((2,) + dims)
-            re, im = t[0], t[1]
-            flat = jnp.stack([pr * re - pi * im, pr * im + pi * re]
-                             ).reshape(2, -1)
-        return flat.reshape(shape)
+            data = _apply_phase(data, _diag_planes(pr, layout),
+                                _diag_planes(pi, layout), layout)
+        return data
     return step, s
 
 
@@ -243,7 +240,7 @@ def _dense_class_step(item, n: int, slot0: int):
                 m2 = _param_matrix(op, params)
                 e = jnp.where(jnp.asarray(mask), m2[(sr, sc)],
                               jnp.zeros((), jnp.complex64))
-            u = e if u is None else e @ u
+            u = e if u is None else jnp.matmul(e, u, precision=A.HIGHEST)
         u = u.astype(jnp.complex64)
         return A.apply_gate_planar(
             data, n, item.qubits,
@@ -285,7 +282,8 @@ class ClassExecutable:
         slot = 0
         for item in self.rep._gate_items():
             if item.kind in ("diag", "perm"):
-                step, slot = _special_class_step(item, self.rep.n, slot)
+                step, slot = _special_class_step(
+                    item, self.rep.n, self.rep.target.lane_qubits, slot)
             else:
                 step, slot = _dense_class_step(item, self.rep.n, slot)
             steps.append(step)
@@ -343,26 +341,10 @@ class ClassExecutable:
             self._batched.move_to_end(key)
         return fn
 
-    def _build(self, with_result: bool, args):
+    def _build(self, with_result: bool):
         program = self._program(with_result)
         in_axes = (None, 0, 0, 0) if with_result else (None, 0, 0)
-        vmapped = jax.vmap(program, in_axes=in_axes)
-        try:
-            jax.eval_shape(vmapped, *args)
-            return jax.jit(vmapped)
-        except Exception:
-            # same fallback as CompiledPlan._build_batched: no batching rule
-            # -> sequential scan inside one jitted program
-            if with_result:
-                def seq(d0, ps, ks, cs):
-                    return jax.lax.map(
-                        lambda pkc: program(d0, pkc[0], pkc[1], pkc[2]),
-                        (ps, ks, cs))
-            else:
-                def seq(d0, ps, cs):
-                    return jax.lax.map(lambda pc: program(d0, pc[0], pc[1]),
-                                       (ps, cs))
-            return jax.jit(seq)
+        return jax.jit(jax.vmap(program, in_axes=in_axes))
 
     def run_class_batch_raw(self, params_matrix, consts, rowkeys=None):
         """Execute stacked class rows; returns the unwaited device output.
@@ -387,7 +369,7 @@ class ClassExecutable:
             with self._plock:
                 fn = self._get_or_build(
                     (int(pm.shape[0]), False),
-                    lambda: self._build(False, (data0, pm, cs)))
+                    lambda: self._build(False))
             return fn(data0, pm, cs)
         rk = jnp.asarray(np.asarray(rowkeys, np.uint32))
         if rk.shape != (pm.shape[0], 2):
@@ -396,7 +378,7 @@ class ClassExecutable:
         with self._plock:
             fn = self._get_or_build(
                 (int(pm.shape[0]), True),
-                lambda: self._build(True, (data0, pm, rk, cs)))
+                lambda: self._build(True))
         return fn(data0, pm, rk, cs)
 
 

@@ -1,24 +1,31 @@
-"""Pallas TPU kernel for fused-gate application (the paper's ApplyGate ROI).
+"""Pallas TPU kernels for fused-gate application (the paper's ApplyGate ROI).
 
-The state is viewed (zero-copy reshape of the flat 2**n index space) as
+The planar state ``f32[2, R, V]`` is viewed (zero-copy reshape of the flat
+``2**n`` index space) as
 
-    f32[2, d_1, d_2, ..., d_m, tail]
+    f32[2, d_1, d_2, ..., d_m, rows, V]
 
-where each gate/control bit is isolated as its own size-2 axis (descending
-significance) and the spans between bits are single axes.  The BlockSpec takes
-the *full* extent of every gate axis and one coordinate of every other axis,
-so a single VMEM block is exactly one state group: 2**k rows x ``tail_blk``
-lanes of re+im — the paper's 2**k scattered unit-stride vector loads, staged
-through VMEM (load-buffering optimization §IV-B).
+where each gate/control bit is its own size-2 axis (descending significance),
+the spans between bits are single axes, and the amplitudes below the lowest
+marked bit form the ``(rows, V)`` tail: whole vector tiles, ``rows`` a
+multiple of the 8 sublanes whenever the marked bits sit above the
+``(8, V)`` tile.  The BlockSpec takes the *full* extent of every gate axis
+and one coordinate of every other axis, so a VMEM block is one state group:
+``2**k`` slabs of ``(rows_blk, V)`` re+im — the paper's ``2**k`` scattered
+unit-stride vector loads, staged through VMEM (load buffering, §IV-B).
 
-Inside the kernel the block collapses to (2, 2**k, tail_blk) and the gate is
-four real matmuls (complex FMA formulation).  For fused degree f = 7 the
-matmul is 128x128 — a native MXU tile (DESIGN.md §2, beyond-paper lever).
+Dense kernel: the block collapses to ``(2, 2**k, rows_blk, V)`` and the
+gate is four real matmuls over the ``2**k`` axis (complex FMA formulation)
+on the MXU at ``precision=HIGHEST``; for ``f = 7`` the matrix is a native
+128x128 tile.
+Control bits are grid axes; the kernel applies the unitary only where every
+control coordinate is 1 and copies through otherwise (predicated iteration).
+Callers move gate bits that fall inside the ``(8, V)`` tile out of it first
+(``ops.apply_fused_gate``).
 
-Controlled gates: control bits are grid axes; the kernel applies the unitary
-only where every control coordinate is 1 and copies through otherwise —
-functionally the paper's predicated iteration.  (A later optimization aliases
-in/out so control-0 blocks are skipped entirely; see EXPERIMENTS.md §Perf.)
+Phase kernel (diagonal clusters): every marked bit above the tile is a grid
+axis, so a block needs one row of the phase table; cluster bits inside the
+tile are folded into that row, which is laid out as a whole ``(8, V)`` tile.
 """
 from __future__ import annotations
 
@@ -31,6 +38,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+
+HIGHEST = jax.lax.Precision.HIGHEST
+SUBLANES = 8
 
 
 @dataclasses.dataclass(frozen=True)
@@ -48,8 +58,14 @@ class ViewPlan:
 
 
 def make_plan(n: int, gate_bits: Sequence[int], ctrl_bits: Sequence[int],
-              max_block_bytes: int = 1 << 20) -> ViewPlan:
-    """Factorize the 2**n index space around the gate/control bits."""
+              max_block_bytes: int = 1 << 20, lanes: int = 128) -> ViewPlan:
+    """Factorize the 2**n index space around the gate/control bits.
+
+    The two trailing axes are the ``(rows, lanes)`` tail below the lowest
+    marked bit (both role ``'tail'``).  Rows are split into ``'seg'`` grid
+    blocks so one block stays within ``max_block_bytes``; a split block keeps
+    at least 8 rows, so its last two dims are whole ``(8, lanes)`` tiles.
+    """
     marked = sorted(
         [(b, "gate") for b in gate_bits] + [(b, "ctrl") for b in ctrl_bits],
         reverse=True)
@@ -65,15 +81,20 @@ def make_plan(n: int, gate_bits: Sequence[int], ctrl_bits: Sequence[int],
         roles.append(role)
         prev = b
     tail = 1 << prev
-    # split the tail so one block stays within the VMEM budget
+    lane_w = min(tail, lanes)
+    rows = tail // lane_w
+    # the matmul pads a gate axis narrower than 8 sublanes to 8 in VMEM,
+    # so a narrow gate's block is sized as if it had 8 partners
     k = len(gate_bits)
-    budget_elems = max(1, max_block_bytes // (2 * 4 * (1 << k) * 2))
-    tail_blk = min(tail, 1 << max(0, budget_elems.bit_length() - 1))
-    if tail // tail_blk > 1:
-        dims.append(tail // tail_blk)
+    budget_rows = max(1, max_block_bytes
+                      // (2 * 4 * max(1 << k, SUBLANES) * lane_w))
+    rows_blk = min(rows, max(min(rows, SUBLANES),
+                             1 << (budget_rows.bit_length() - 1)))
+    if rows // rows_blk > 1:
+        dims.append(rows // rows_blk)
         roles.append("seg")
-    dims.append(tail_blk)
-    roles.append("tail")
+    dims += [rows_blk, lane_w]
+    roles += ["tail", "tail"]
 
     block = tuple(2 if r == "gate" else (d if r == "tail" else 1)
                   for d, r in zip(dims, roles))
@@ -93,23 +114,29 @@ def _unravel(flat, sizes: Sequence[int]):
     return coords
 
 
+def _state_spec(plan: ViewPlan) -> pl.BlockSpec:
+    def idx_map(g):
+        return (0,) + tuple(_unravel(g, plan.grid_sizes))
+    return pl.BlockSpec((2,) + plan.block, idx_map)
+
+
 def _kernel(u_re_ref, u_im_ref, x_ref, o_ref, *, plan: ViewPlan):
-    k = plan.k
-    tail_blk = plan.block[-1]
+    m = 1 << plan.k
+    rows_blk, lane_w = plan.block[-2:]
     ctrl_axes = [i for i, r in enumerate(plan.roles) if r == "ctrl"]
 
     def compute():
-        x = x_ref[...]
-        x = x.reshape(2, 1 << k, tail_blk)
+        x = x_ref[...].reshape(2, m, rows_blk, lane_w)
         re, im = x[0], x[1]
+        plane = x_ref.shape[1:]
         u_re = u_re_ref[...]
         u_im = u_im_ref[...]
-        # complex matvec as four real matmuls (fp32 accumulation)
-        o_re = jnp.dot(u_re, re, preferred_element_type=jnp.float32) - \
-            jnp.dot(u_im, im, preferred_element_type=jnp.float32)
-        o_im = jnp.dot(u_re, im, preferred_element_type=jnp.float32) + \
-            jnp.dot(u_im, re, preferred_element_type=jnp.float32)
-        o_ref[...] = jnp.stack([o_re, o_im]).reshape(x_ref.shape)
+        dot = functools.partial(jnp.einsum, "ij,jrl->irl", precision=HIGHEST,
+                                preferred_element_type=jnp.float32)
+        # complex matvec as four real matmuls (fp32 accumulation); each
+        # plane is stored as it is done, so at most one is held in VMEM
+        o_ref[0] = (dot(u_re, re) - dot(u_im, im)).reshape(plane)
+        o_ref[1] = (dot(u_re, im) + dot(u_im, re)).reshape(plane)
 
     if not ctrl_axes:
         compute()
@@ -130,78 +157,14 @@ def _kernel(u_re_ref, u_im_ref, x_ref, o_ref, *, plan: ViewPlan):
         o_ref[...] = x_ref[...]
 
 
-def _diag_kernel(p_re_ref, p_im_ref, idx_ref, x_ref, o_ref, *,
-                 plan: ViewPlan, has_perm: bool, has_phase: bool):
-    """Diagonal / permutation fast path: stream one VMEM block and apply the
-    broadcast phase in-register — the load-buffering path of the dense
-    kernel without the matmul (6 real flops per amplitude instead of
-    ``8 * 2**k``).  A monomial cluster's static index map is a row gather of
-    the block (``idx_ref``, a VMEM-resident constant); controls were folded
-    into the phase vector at lowering, so there is no predication."""
-    k = plan.k
-    tail_blk = plan.block[-1]
-    x = x_ref[...]
-    x = x.reshape(2, 1 << k, tail_blk)
-    re, im = x[0], x[1]
-    if has_perm:
-        idx = idx_ref[...].reshape(1 << k)
-        re = jnp.take(re, idx, axis=0)
-        im = jnp.take(im, idx, axis=0)
-    if has_phase:
-        p_re = p_re_ref[...].reshape(1 << k, 1)
-        p_im = p_im_ref[...].reshape(1 << k, 1)
-        re, im = p_re * re - p_im * im, p_re * im + p_im * re
-    o_ref[...] = jnp.stack([re, im]).reshape(x_ref.shape)
-
-
-def apply_diag_gate_kernel(data_flat: jax.Array, p_re: jax.Array | None,
-                           p_im: jax.Array | None, plan: ViewPlan,
-                           perm=None, interpret: bool = True) -> jax.Array:
-    """Run the diag/perm kernel on the flat planar state f32[2, 2**n]."""
-    shaped = data_flat.reshape((2,) + plan.dims)
-
-    def idx_map(g):
-        coords = _unravel(g, plan.grid_sizes)
-        return (0,) + tuple(coords)
-
-    spec = pl.BlockSpec((2,) + plan.block, idx_map)
-    has_phase = p_re is not None
-    has_perm = perm is not None
-    dim = 1 << plan.k
-    if not has_phase:                    # pure permutation: phase refs unused
-        p_re = p_im = jnp.ones((dim, 1), jnp.float32)
-    idx_in = jnp.asarray(perm if has_perm else np.zeros(dim),
-                         jnp.int32).reshape(dim, 1)
-    p_spec = pl.BlockSpec((dim, 1), lambda g: (0, 0))
-
-    out = pl.pallas_call(
-        functools.partial(_diag_kernel, plan=plan, has_perm=has_perm,
-                          has_phase=has_phase),
-        grid=(plan.grid,),
-        in_specs=[p_spec, p_spec, p_spec, spec],
-        out_specs=spec,
-        out_shape=jax.ShapeDtypeStruct(shaped.shape, jnp.float32),
-        interpret=interpret,
-    )(jnp.asarray(p_re, jnp.float32).reshape(dim, 1),
-      jnp.asarray(p_im, jnp.float32).reshape(dim, 1), idx_in, shaped)
-    return out.reshape(data_flat.shape)
-
-
 def apply_fused_gate_kernel(data_flat: jax.Array, u_re: jax.Array,
                             u_im: jax.Array, plan: ViewPlan,
-                            interpret: bool = True) -> jax.Array:
-    """Run the kernel on the flat planar state f32[2, 2**n]."""
+                            interpret: bool) -> jax.Array:
+    """Run the dense kernel on the flat planar state f32[2, 2**n]."""
     shaped = data_flat.reshape((2,) + plan.dims)
-
-    def idx_map(g):
-        coords = _unravel(g, plan.grid_sizes)
-        return (0,) + tuple(coords)
-
-    zero_map = lambda g: (0, 0)
-    spec = pl.BlockSpec((2,) + plan.block, idx_map)
+    spec = _state_spec(plan)
     dim = u_re.shape[0]
-    u_spec = pl.BlockSpec((dim, dim), zero_map)
-
+    u_spec = pl.BlockSpec((dim, dim), lambda g: (0, 0))
     out = pl.pallas_call(
         functools.partial(_kernel, plan=plan),
         grid=(plan.grid,),
@@ -210,4 +173,68 @@ def apply_fused_gate_kernel(data_flat: jax.Array, u_re: jax.Array,
         out_shape=jax.ShapeDtypeStruct(shaped.shape, jnp.float32),
         interpret=interpret,
     )(u_re, u_im, shaped)
+    return out.reshape(data_flat.shape)
+
+
+def _phase_kernel(p_ref, x_ref, o_ref, *, tile_rows: int):
+    """Rotate one state block by its phase tile: ``p_ref`` is the block's
+    ``(2, tile_rows, V)`` phase row (re, im), repeated over the block's
+    row groups."""
+    p = p_ref[...]
+    p_re, p_im = p[0], p[1]
+    x = x_ref[...]
+    shape = x.shape
+    rows, lane_w = shape[-2:]
+    x = x.reshape(2, rows // tile_rows, tile_rows, lane_w)
+    re, im = x[0], x[1]
+    o_ref[...] = jnp.stack([p_re * re - p_im * im,
+                            p_re * im + p_im * re]).reshape(shape)
+
+
+def phase_tile_map(qubits: Sequence[int], tile_bits: int) -> np.ndarray:
+    """int32[2**tile_bits]: the cluster-index bits held by each position of
+    the low ``tile_bits`` amplitude bits (cluster bit ``m`` <-> sorted
+    ``qubits[m]``; the tile's cluster bits are the low cluster bits)."""
+    pos = np.arange(1 << tile_bits)
+    low = [q for q in sorted(qubits) if q < tile_bits]
+    out = np.zeros_like(pos)
+    for m, q in enumerate(low):
+        out |= ((pos >> q) & 1) << m
+    return out.astype(np.int32)
+
+
+def apply_phase_kernel(data_flat: jax.Array, table: jax.Array,
+                       hi_bits: Sequence[int], n: int, tile_rows: int,
+                       lanes: int, interpret: bool,
+                       max_block_bytes: int = 1 << 20) -> jax.Array:
+    """Run the phase kernel on the flat planar state f32[2, 2**n].
+
+    ``table`` is ``f32[2, 2**len(hi_bits) * tile_rows, lanes]``: for each
+    pattern of the cluster bits above the tile (``hi_bits``, sorted; bit
+    ``m`` of the pattern <-> ``hi_bits[m]``) one ``(tile_rows, lanes)``
+    phase tile.
+    """
+    plan = make_plan(n, (), tuple(hi_bits), max_block_bytes=max_block_bytes,
+                     lanes=lanes)
+    shaped = data_flat.reshape((2,) + plan.dims)
+    hi_axes = [i for i, r in enumerate(plan.roles) if r == "ctrl"]
+    # hi axes come MSB first: axis j holds hi bit len-1-j
+    weights = [1 << (len(hi_axes) - 1 - j) for j in range(len(hi_axes))]
+
+    def table_map(g):
+        coords = _unravel(g, plan.grid_sizes)
+        row = 0
+        for a, w in zip(hi_axes, weights):
+            row = row + coords[a] * w
+        return (0, row, 0)
+
+    spec = _state_spec(plan)
+    out = pl.pallas_call(
+        functools.partial(_phase_kernel, tile_rows=tile_rows),
+        grid=(plan.grid,),
+        in_specs=[pl.BlockSpec((2, tile_rows, lanes), table_map), spec],
+        out_specs=spec,
+        out_shape=jax.ShapeDtypeStruct(shaped.shape, jnp.float32),
+        interpret=interpret,
+    )(table, shaped)
     return out.reshape(data_flat.shape)

@@ -1,9 +1,10 @@
 """Pure-jnp oracle for the fused-gate kernel.
 
 Deliberately takes a different code path from the kernel: the planar state is
-converted to the dense complex vector, the gate is applied with the complex
-tensor-contraction reference (``core.apply.apply_gate_dense``), and the result
-converted back — so a bug in the planar index math cannot cancel out.
+converted to the dense complex vector, the gate is applied with the flat-vector
+reference (``core.apply.apply_gate_dense``: shifted partners selected by index
+bits, no views and no bit exchanges), and the result converted back — so a bug
+in the planar index math or the tile-bit exchanges cannot cancel out.
 """
 from __future__ import annotations
 

@@ -1,18 +1,18 @@
-"""Jit'd wrapper + pure-jnp reference for the expectation kernel."""
+"""Public wrapper + pure-jnp reference for the expectation kernel."""
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.apply_gate.apply_gate import make_plan
+from repro.core.target import resolve_interpret
 from repro.kernels.expectation.expectation import expectation_z_kernel
 
 
 def expectation_z(data: jax.Array, n: int, v: int, qubit: int,
-                  interpret: bool = True) -> jax.Array:
-    plan = make_plan(n, (qubit,), ())
-    return expectation_z_kernel(data.reshape(2, 1 << n), plan,
-                                interpret=interpret)
+                  interpret: bool | None = None) -> jax.Array:
+    """<Z_qubit> of the lane-tiled planar state ``f32[2, R, 2**v]``."""
+    return expectation_z_kernel(data.reshape(2, 1 << (n - v), 1 << v), qubit,
+                                interpret=resolve_interpret(interpret))
 
 
 def expectation_z_ref(data: jax.Array, n: int, v: int, qubit: int) -> jax.Array:

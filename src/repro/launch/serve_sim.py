@@ -17,6 +17,9 @@ plan-cache statistics.
   PYTHONPATH=src python -m repro.launch.serve_sim --mode ingest --clients 4 \
       --max-wait-ms 2 --requests 128
 
+The target and the Pallas interpret mode follow the device JAX finds.  The
+exit code is non-zero when any request failed.
+
 Telemetry (docs/OBSERVABILITY.md): ``--trace FILE`` records every request's
 lifecycle span and writes a Chrome-trace/Perfetto JSON (``--trace-jsonl`` the
 raw event log), ``--metrics-json FILE`` exports the unified metrics-registry
@@ -31,16 +34,16 @@ import time
 import numpy as np
 
 from repro.core import circuits as C
-from repro.core.target import get_target
 from repro.engine import (BatchExecutor, BatchScheduler, FaultInjector,
                           IngestRejected, IngestServer, PlanBreaker,
                           ResultSpec, RetryPolicy, SpanTracer, depolarizing,
                           engine_registry, hea_template, qaoa_template,
                           template_of)
+from repro.launch.compile_cache import enable_compile_cache
 from repro.testing import run_producers
 
 
-def _make_traffic(workload: str, n: int, requests: int, seed: int):
+def make_traffic(workload: str, n: int, requests: int, seed: int):
     """Yield (template, params) pairs for a synthetic request mix."""
     rng = np.random.default_rng(seed)
     templates = []
@@ -197,7 +200,6 @@ def main(argv=None):
                     choices=["qaoa", "hea", "mixed"])
     ap.add_argument("--backend", default="planar",
                     choices=["dense", "planar", "pallas"])
-    ap.add_argument("--target", default="cpu_test")
     ap.add_argument("--max-batch", type=int, default=64)
     ap.add_argument("--mode", default="async",
                     choices=["sync", "async", "ingest"],
@@ -298,6 +300,7 @@ def main(argv=None):
                          "async speedup")
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     injector = None
     if args.chaos is not None:
         injector = FaultInjector(seed=args.chaos_seed,
@@ -308,8 +311,7 @@ def main(argv=None):
     if retries is None and args.chaos is not None:
         retries = 3            # chaos without a retry policy would just fail
     retry = RetryPolicy(max_retries=retries) if retries is not None else None
-    executor = BatchExecutor(target=get_target(args.target),
-                             backend=args.backend, f=args.f,
+    executor = BatchExecutor(backend=args.backend, f=args.f,
                              specialize=args.specialize == "on",
                              mesh=args.mesh,
                              max_local_qubits=args.max_local_qubits,
@@ -329,7 +331,7 @@ def main(argv=None):
                            retry=retry,
                            class_routing=args.class_routing,
                            capacity_factor=args.capacity_factor)
-    traffic = _make_traffic(args.workload, args.qubits, args.requests,
+    traffic = make_traffic(args.workload, args.qubits, args.requests,
                             args.seed)
     result = _make_result_spec(args, args.qubits)
 
@@ -372,7 +374,7 @@ def main(argv=None):
 
     if args.compare_sync:
         sync_sched = BatchScheduler(
-            BatchExecutor(target=get_target(args.target),
+            BatchExecutor(target=executor.target,
                           backend=args.backend, f=args.f,
                           specialize=args.specialize == "on",
                           mesh=args.mesh,
@@ -397,7 +399,9 @@ def main(argv=None):
               f"(the {args.mode} time above includes its "
               f"{rep['cache_compiles']} plan compiles; see benchmarks/"
               f"serve_mixed.py for the steady-state comparison)")
-    return 0
+        if sync_rep["failed"]:
+            return 1
+    return 1 if rep["failed"] else 0
 
 
 if __name__ == "__main__":

@@ -364,7 +364,7 @@ def moe_fwd(p, cfg: ModelConfig, x: jax.Array) -> jax.Array:
 
     xt = x.reshape(t_global, d)
     specs = SH.batch_axes() + (SH.MODEL_AXIS,)
-    fn = SH.shard_map(
+    fn = jax.shard_map(
         local_moe, mesh=mesh,
         in_specs=(jax.sharding.PartitionSpec(specs), jax.sharding.PartitionSpec(),
                   jax.sharding.PartitionSpec(SH.MODEL_AXIS),

@@ -24,13 +24,6 @@ _MESH: Optional[Mesh] = None
 BATCH_AXES = ("pod", "data")
 MODEL_AXIS = "model"
 
-# jax.shard_map graduated from jax.experimental in newer releases; resolve
-# whichever this jax ships so call sites stay version-agnostic.
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:  # pragma: no cover - depends on installed jax
-    from jax.experimental.shard_map import shard_map  # noqa: F401
-
 
 def set_mesh(mesh: Optional[Mesh]) -> None:
     global _MESH

@@ -1,0 +1,137 @@
+"""Compile rehearsal for one TPU v5e: the main-path kernels at real size.
+
+Each test compiles (nothing runs) for a described ``v5e:2x2`` chip at
+n = 24 and checks that the program's temporaries stay within 4x the state.
+The topology is described inside a fixture, so a process that cannot load
+the TPU compiler skips these tests and no module import touches it.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from repro.core import apply as A
+from repro.core import circuits as C
+from repro.core.target import TPU_V5E
+from repro.engine.plan import compile_plan
+from repro.engine.template import template_of
+from repro.kernels.apply_gate import ops as K
+from repro.kernels.expectation import ops as E
+
+N = 24
+V = TPU_V5E.lane_qubits
+STATE = jax.ShapeDtypeStruct((2, 1 << (N - V), 1 << V), jnp.float32)
+STATE_BYTES = 2 * 4 << N
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    from jax.sharding import SingleDeviceSharding
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler in this process
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip cannot be read back from the
+    # persistent cache without the chip: keep the cache out of it
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", enabled)
+
+
+def _compile(one_chip, fn, *args, donate=True):
+    """Compile ``fn`` (state first, donated when ``fn`` returns a state) for
+    the chip; returns the temporaries in bytes."""
+    specs = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+             for a in args]
+    compiled = jax.jit(fn, donate_argnums=(0,) if donate else ()).lower(
+        *specs).compile()
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp <= 4 * STATE_BYTES, f"temporaries {temp} > 4x state"
+    return temp
+
+
+def _unitary(k):
+    return jax.ShapeDtypeStruct((1 << k, 1 << k), jnp.float32)
+
+
+@pytest.mark.parametrize("qubits", [(10, 11), (2, 20)])
+def test_planar_gate(one_chip, qubits):
+    k = len(qubits)
+    _compile(one_chip,
+             lambda d, ur, ui: A.apply_gate_planar(d, N, qubits, ur, ui),
+             STATE, _unitary(k), _unitary(k))
+
+
+@pytest.mark.parametrize("qubits,controls", [
+    ((0,), ()), ((3, 7), ()), ((15, 16, 17, 18, 19), ()), ((4,), (12,))])
+def test_pallas_fused_gate(one_chip, qubits, controls):
+    k = len(qubits)
+    _compile(one_chip,
+             lambda d, ur, ui: K.apply_fused_gate(
+                 d, N, V, qubits, ur, ui, controls=controls,
+                 interpret=False),
+             STATE, _unitary(k), _unitary(k))
+
+
+@pytest.mark.parametrize("qubits,perm", [
+    ((2, 9, 13, 21), None), ((0, 1, 2, 3, 4, 5, 6, 7, 8, 9, 10, 11), None),
+    ((3, 12, 20), np.array([1, 0, 3, 2, 5, 4, 7, 6]))])
+def test_pallas_phase_gate(one_chip, qubits, perm):
+    w = 1 << len(qubits)
+    _compile(one_chip,
+             lambda d, pr, pi: K.apply_phase_gate(d, N, V, qubits, pr, pi,
+                                                  perm=perm, interpret=False),
+             STATE, jax.ShapeDtypeStruct((w,), jnp.float32),
+             jax.ShapeDtypeStruct((w,), jnp.float32))
+
+
+@pytest.mark.parametrize("qubit", [3, 9, 20])
+def test_expectation_z(one_chip, qubit):
+    _compile(one_chip,
+             lambda d: E.expectation_z(d, N, V, qubit, interpret=False),
+             STATE, donate=False)
+
+
+def test_planar_qrc_plan(one_chip):
+    plan = compile_plan(template_of(C.qrc(N, depth=8)), backend="planar",
+                        target=TPU_V5E)
+    params = jax.ShapeDtypeStruct((plan.num_params,), jnp.float32)
+    _compile(one_chip, plan._program(), STATE, params)
+
+
+@pytest.mark.parametrize("qubit,controls", [(0, ()), (3, (12,)), (20, (2,))])
+def test_dense_reference_gate(one_chip, qubit, controls):
+    """The one-qubit (optionally controlled) gates the dense reference runs
+    for a QRC; each of the 3**k partner shifts of a k-qubit gate is a
+    state-sized temporary on the chip."""
+    psi = jax.ShapeDtypeStruct((1 << N,), jnp.complex64)
+    u = jax.ShapeDtypeStruct((2, 2), jnp.complex64)
+    _compile(one_chip,
+             lambda p, m: A.apply_gate_dense(p, N, (qubit,), m, controls),
+             psi, u)
+
+
+
+
+def test_planar_x_layer_plan(one_chip):
+    """X layers lower to XOR-mask permutations, which are axis reversals:
+    no ``2**n`` index map to compile."""
+    from repro.core import gates as G
+    gs = [G.h(q) for q in range(N)]
+    gs += [G.cz(q, q + 1) for q in range(N - 1)]
+    gs += [G.x(q) for q in range(0, N, 3)]
+    gs += [G.cz(q, q + 1) for q in range(N - 1)]
+    gs += [G.x(q) for q in range(N)]
+    plan = compile_plan(template_of(C.Circuit(N, gs)), backend="planar",
+                        target=TPU_V5E)
+    assert any(it.kind == "perm" for it in plan.items)
+    params = jax.ShapeDtypeStruct((plan.num_params,), jnp.float32)
+    _compile(one_chip, plan._program(), STATE, params)

@@ -215,3 +215,128 @@ def test_probabilities_dense_basis_order(backend):
     # State.probabilities agrees with |to_dense()|^2 of the same state
     np.testing.assert_allclose(probs, np.abs(_dense(state)) ** 2, atol=1e-6)
     assert probs.shape == (1 << circ.n,)
+
+
+def test_batched_build_raises_instead_of_falling_back(monkeypatch):
+    """A program with no batching rule is an error, not a silent switch to a
+    sequential lax.map (which the parent fell back to)."""
+    import jax
+    import jax.numpy as jnp
+    ex = BatchExecutor(target=CPU_TEST, backend="planar", cache=PlanCache())
+    plan = ex.plan_for(qaoa_template(4, 1))
+
+    def unbatchable():
+        def program(state, params):
+            zero = jax.pure_callback(lambda p: np.float32(0),
+                                     jax.ShapeDtypeStruct((), jnp.float32),
+                                     params[0])
+            return state + zero
+        return program
+
+    monkeypatch.setattr(plan, "_program", unbatchable)
+    with pytest.raises(NotImplementedError, match="vmap"):
+        plan.run_batch_raw(np.zeros((2, plan.num_params), np.float32))
+
+
+@pytest.mark.parametrize("backend,n", [("planar", 16), ("pallas", 20)])
+def test_tiled_target_plans_keep_vector_tiles_whole(backend, n):
+    """On a tiled-memory target every non-diagonal item can move its tile
+    bits out of the vector tile, and the plan still matches dense."""
+    from repro.core.apply import lane_window
+    from repro.core.target import TPU_V5E
+    from repro.kernels.apply_gate.ops import tile_swaps
+    v = TPU_V5E.lane_qubits
+    circ = C.qrc(n, depth=3)
+    sim = Simulator(TPU_V5E, backend=backend, plan_cache=PlanCache())
+    plan = sim.plan_for(circ)
+    for it in plan.items:
+        bits = it.qubits + it.controls
+        if it.kind == "diag" or min(bits) >= (v if backend == "planar"
+                                              else v + 3):
+            continue
+        if backend == "planar":
+            assert lane_window(n, v, bits) is not None, bits
+        else:
+            assert tile_swaps(n, v, bits), bits
+    ref = Simulator(TPU_V5E, backend="dense", plan_cache=PlanCache())
+    np.testing.assert_allclose(_dense(sim.run(circ)), _dense(ref.run(circ)),
+                               atol=2e-6)
+
+
+
+def x_layer_circuit(n):
+    """X layers after CZ ladders: the planar plan folds them into XOR-mask
+    permutation items, some with lane bits."""
+    from repro.core import gates as G
+    gs = [G.h(q) for q in range(n)]
+    gs += [G.cz(q, q + 1) for q in range(n - 1)]
+    gs += [G.x(q) for q in range(0, n, 3)]
+    gs += [G.cz(q, q + 1) for q in range(n - 1)]
+    gs += [G.x(q) for q in range(n)]
+    return C.Circuit(n, gs, name="xlayers")
+
+
+def test_xor_permutations_lower_to_flips():
+    """X layers on lane and row bits of a tiled target lower to axis
+    reversals: the planar program holds no gather and matches dense."""
+    import jax
+    import jax.numpy as jnp
+    from repro.core.target import TPU_V5E
+    n = 16
+    circ = x_layer_circuit(n)
+    sim = Simulator(TPU_V5E, backend="planar", plan_cache=PlanCache())
+    plan = sim.plan_for(circ)
+    perms = [it for it in plan.items if it.kind == "perm"]
+    assert any(min(it.qubits) < TPU_V5E.lane_qubits for it in perms)
+    state = jax.ShapeDtypeStruct((2, 1 << (n - 7), 1 << 7), jnp.float32)
+    params = jax.ShapeDtypeStruct((plan.num_params,), jnp.float32)
+    hlo = jax.jit(plan._program()).lower(state, params).as_text()
+    assert "gather" not in hlo
+    ref = Simulator(TPU_V5E, backend="dense", plan_cache=PlanCache())
+    np.testing.assert_allclose(_dense(sim.run(circ)), _dense(ref.run(circ)),
+                               atol=2e-6)
+
+
+def _dot_precisions(jaxpr, out):
+    """Precision of every dot_general in ``jaxpr`` and its sub-programs."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            out.append(eqn.params["precision"])
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else (value,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    _dot_precisions(sub, out)
+    return out
+
+
+@pytest.mark.parametrize("backend", BACKENDS)
+@pytest.mark.parametrize("expectation", (False, True))
+def test_program_dots_use_highest_precision(backend, expectation):
+    """A TPU runs a default-precision f32/c64 dot as bf16 passes, so every
+    dot a plan traces (parameterized cluster unitaries, gate matvecs,
+    observable inner products) asks for HIGHEST."""
+    import jax
+    import jax.numpy as jnp
+    from repro.engine import ResultSpec
+    from repro.engine.shapeclass import ClassExecutable, class_row_tensors
+    spec = (ResultSpec.expectation([{0: "Z"}, {3: "X"}]) if expectation
+            else None)
+    plan = PlanCache().get_or_compile(hea_template(8, 2), backend=backend,
+                                      target=CPU_TEST, result=spec)
+    state = plan._initial_data(None)
+    params = jnp.zeros(plan.num_params, jnp.float32)
+    if expectation:
+        programs = [jax.make_jaxpr(plan._result_program())(
+            state, params, jnp.zeros(2, jnp.uint32))]
+    else:
+        programs = [jax.make_jaxpr(plan._program())(state, params)]
+    if backend == "planar" and not expectation:
+        consts = [jnp.asarray(c) for c in class_row_tensors(plan)]
+        programs.append(jax.make_jaxpr(
+            ClassExecutable(plan)._program(False))(state, params, consts))
+    for jp in programs:
+        precisions = _dot_precisions(jp.jaxpr, [])
+        assert precisions or (backend == "dense" and not expectation)
+        hi = jax.lax.Precision.HIGHEST
+        assert all(p == (hi, hi) for p in precisions), precisions

@@ -78,6 +78,34 @@ def test_controlled(controls):
     np.testing.assert_allclose(out, ref, atol=3e-6)
 
 
+@pytest.mark.parametrize("qubits,controls", [
+    ((0,), ()), ((4, 9), ()), ((1,), (4,)), ((0, 1, 2, 3, 4), ()),
+    ((2, 7, 11), (5,))])
+def test_tile_bits_moved_out(qubits, controls):
+    """n = 14 leaves free blocks for the lane and sublane bits of the
+    8-lane target, so the kernel sees whole (8, 8) tiles."""
+    out, ref = _run_both(14, qubits, controls=controls, seed=17)
+    np.testing.assert_allclose(out, ref, atol=3e-6)
+
+
+@pytest.mark.parametrize("qubits,perm", [
+    ((1, 4, 8, 12), None), ((0, 2, 3, 5), None), ((9, 13), None),
+    ((1, 4, 10), np.array([3, 2, 1, 0, 7, 6, 5, 4]))])
+def test_phase_gate_tiles(qubits, perm):
+    """Diagonal clusters with bits in the lane, sublane and row ranges,
+    and a permutation cluster, against the dense-matrix oracle."""
+    from repro.kernels.apply_gate.ops import apply_phase_gate
+    from repro.kernels.apply_gate.ref import apply_phase_gate_ref
+    n = 14
+    st_ = SV.random_state(n, CPU_TEST, seed=3)
+    ang = np.random.default_rng(4).uniform(0, 2 * np.pi, 1 << len(qubits))
+    pr = jnp.asarray(np.cos(ang), jnp.float32)
+    pi = jnp.asarray(np.sin(ang), jnp.float32)
+    out = apply_phase_gate(st_.data, n, st_.v, qubits, pr, pi, perm=perm)
+    ref = apply_phase_gate_ref(st_.data, n, st_.v, qubits, pr, pi, perm=perm)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=3e-6)
+
+
 def test_unsorted_qubits_matrix_permutation():
     """qubits=(5, 1) must equal qubits=(1, 5) with permuted U."""
     out, ref = _run_both(7, (5, 1), seed=13)
@@ -120,7 +148,8 @@ def test_plan_tail_split_respects_budget():
 
 # -- expectation kernel -------------------------------------------------------
 
-@pytest.mark.parametrize("n,q", [(6, 0), (6, 3), (6, 5), (9, 4)])
+@pytest.mark.parametrize("n,q", [(6, 0), (6, 3), (6, 5), (9, 4), (14, 1),
+                                 (14, 4), (14, 12)])
 def test_expectation_z(n, q):
     st_ = SV.random_state(n, CPU_TEST, seed=q)
     k = float(expectation_z(st_.data, n, st_.v, q))

@@ -276,7 +276,7 @@ def test_small_n_auto_fusion_correct_on_lane_tiled(backend):
     t = qaoa_template(n, 2)
     rng = np.random.default_rng(5)
     pm = rng.uniform(-np.pi, np.pi, (3, t.num_params)).astype(np.float32)
-    ex = BatchExecutor(backend=backend, cache=PlanCache())
+    ex = BatchExecutor(target=CPU_TEST, backend=backend, cache=PlanCache())
     states = ex.run_batch(t, pm)
     plan = ex.plan_for(t)
     assert plan.f <= max(2, n - CPU_TEST.lane_qubits)
