@@ -26,6 +26,9 @@ EL005     unseeded randomness in tests: bare ``random.*`` /
           ``np.random.*`` calls (or zero-arg ``default_rng()`` /
           ``Random()``) make failures unreproducible — construct a
           seeded generator and log the seed.
+EL006     kernel attribution: every ``pallas_call`` under ``kernels/``
+          passes ``name=`` and ``metadata=`` (``scopes.kernel_metadata()``),
+          so each kernel execution in a profile names its plan item.
 SYNTAX    the file failed to parse (guards the tools/ scripts in CI).
 ========  ==============================================================
 
@@ -49,6 +52,7 @@ RULES = {
     "EL003": "tracer record not gated on .enabled",
     "EL004": "host sync inside a poll/drain loop body",
     "EL005": "unseeded randomness in tests",
+    "EL006": "pallas_call without name= and metadata=",
     "SYNTAX": "file failed to parse",
 }
 
@@ -230,6 +234,7 @@ class _FileLinter:
                       f"(write `# lint-ok: {rule} <why this is safe>`)")
         self.in_engine = "/engine/" in f"/{relpath}"
         self.in_tests = relpath.startswith("tests/") or "/tests/" in relpath
+        self.in_kernels = "/kernels/" in f"/{relpath}"
         self.is_tracer_impl = relpath.endswith("telemetry.py")
         self.decls_by_line = _guarded_decls(self.lines)
 
@@ -259,6 +264,8 @@ class _FileLinter:
         self._lint_drain_sync(tree)
         if self.in_tests:
             self._lint_randomness(tree)
+        if self.in_kernels:
+            self._lint_kernel_names(tree)
         return self.findings
 
     # -- scope bookkeeping --
@@ -466,6 +473,22 @@ class _FileLinter:
                       f"{'.'.join(chain)}(...) draws from the hidden "
                       "global stream — use a seeded Generator and log "
                       "the seed")
+
+
+    # -- EL006 --
+    def _lint_kernel_names(self, tree: ast.Module) -> None:
+        for node in ast.walk(tree):
+            if not (isinstance(node, ast.Call)
+                    and _attr_chain(node.func)[-1] == "pallas_call"):
+                continue
+            given = {kw.arg for kw in node.keywords}
+            missing = [k for k in ("name", "metadata") if k not in given]
+            if missing:
+                self.emit(node, "EL006", self._scope_of(tree, node),
+                          "pallas_call",
+                          f"pallas_call(...) without {' and '.join(missing)}"
+                          "= — a profile could not tell which plan item "
+                          "this kernel ran for")
 
 
 # -- public API ---------------------------------------------------------------

@@ -21,7 +21,7 @@ bits merged, ``(..., d_hi, 2, d_mid, 2, d_lo)`` (:func:`span_view`), so the
 rank grows with the gate's width and never with ``n``.  A marked bit below
 the lane boundary would make ``d_lo`` narrower than a vector tile, which a
 TPU pads to whole (8, 128) tiles; instead the lane bits are first exchanged
-with a free block of row bits (:func:`lane_window`, :func:`swap_bits` — one
+with a free block of row bits (:func:`lane_window`, :func:`exchange` — one
 transpose), so the gate only ever touches row axes.
 
 Conventions: see ``repro.core.gates``.
@@ -35,6 +35,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import scopes
 from repro.core.gates import Gate
 
 HIGHEST = jax.lax.Precision.HIGHEST
@@ -77,27 +78,33 @@ def lane_window(n: int, v: int, bits: Sequence[int]) -> int | None:
     return None
 
 
-def swap_bits(x: jax.Array, n: int, lo: int, w: int, s: int) -> jax.Array:
+def _lead(shape: tuple[int, ...], n: int) -> tuple[int, ...]:
+    """The leading axes of ``shape``, before the trailing ones that flatten
+    to the ``2**n`` amplitude index."""
+    size = int(np.prod(shape))
+    for k, d in enumerate(shape):
+        if size == 1 << n:
+            return tuple(shape[:k])
+        size //= d
+    raise ValueError(f"no trailing axes of {shape} flatten to 2**{n}")
+
+
+def exchange(x: jax.Array, n: int, lo: int, w: int, s: int) -> jax.Array:
     """Exchange amplitude bits ``[lo, lo + w)`` with ``[s, s + w)``, where
     ``s >= lo + w`` (an involution: one transpose of two ``2**w`` axes).
     ``x`` is any array whose trailing axes flatten to the ``2**n``
-    amplitude index; leading axes are kept."""
-    shape = x.shape
-    lead = shape[:-1] if shape[-1] == 1 << n else shape[:-2]
-    t = x.reshape(lead + (1 << (n - s - w), 1 << w, 1 << (s - lo - w),
-                          1 << w, 1 << lo))
-    t = jnp.swapaxes(t, len(lead) + 1, len(lead) + 3)
-    return t.reshape(shape)
+    amplitude index; leading axes are kept.  The result is left in the
+    view ``(*lead, 2**(n-s-w), 2**w, 2**(s-lo-w), 2**w, 2**lo)`` for the
+    caller to reshape once: the conversion to HLO merges two reshapes in
+    a row into one new reshape, which keeps none of the device attributes
+    (:mod:`repro.core.scopes`).  Its operations carry
+    ``repro_part="exchange"``."""
+    lead = _lead(x.shape, n)
+    with scopes.device_scope(part="exchange"):
+        t = x.reshape(lead + (1 << (n - s - w), 1 << w, 1 << (s - lo - w),
+                              1 << w, 1 << lo))
+        return jnp.swapaxes(t, len(lead) + 1, len(lead) + 3)
 
-
-def _lane_bits_out(x, n: int, v: int, qubits, controls):
-    """Move lane gate bits to a free row block: ``(x, qubits, controls, s)``
-    with the bits renamed; ``s`` is None when nothing moved."""
-    s = lane_window(n, v, tuple(qubits) + tuple(controls))
-    if s is None:
-        return x, tuple(qubits), tuple(controls), None
-    mv = lambda bs: tuple(b + s if b < v else b for b in bs)
-    return swap_bits(x, n, 0, v, s), mv(qubits), mv(controls), s
 
 
 def _partner_slices(t, axis, qubits, lead: int) -> list:
@@ -189,7 +196,8 @@ def _dense_gate(psi, n: int, qubits: tuple[int, ...], u,
 
 # -- planar design: f32[2, R, V] ----------------------------------------------
 
-def _planar_rows(data, n: int, qubits, u_re, u_im, controls):
+def _planar_rows(data, n: int, v: int, qubits, u_re, u_im, controls):
+    """The gate on row bits only, in the span view ``(2, *dims)``."""
     dims, axis = span_view(n, tuple(qubits) + tuple(controls))
     t = data.reshape((2,) + dims)
     k = len(qubits)
@@ -210,7 +218,7 @@ def _planar_rows(data, n: int, qubits, u_re, u_im, controls):
         rest = s.shape[k + 1:]
         # the columns keep their lane axis: (2**k, rows, V) compiles far
         # faster on a TPU than one (2**k, rows * V) matrix
-        lanes = min(data.shape[-1], int(np.prod(rest)))
+        lanes = min(1 << v, int(np.prod(rest)))
         s = s.reshape(2, 1 << k, -1, lanes)
         mm = functools.partial(jnp.einsum, "ij,jrl->irl", precision=HIGHEST)
         # complex matvec as 4 real matmuls (paper's FMA formulation)
@@ -221,7 +229,23 @@ def _planar_rows(data, n: int, qubits, u_re, u_im, controls):
     mask = _control_mask(dims, axis, controls, 1)
     if mask is not None:
         out = jnp.where(mask, out, t)
-    return out.reshape(data.shape)
+    return out
+
+
+def apply_planar(data: jax.Array, n: int, v: int, qubits: tuple[int, ...],
+                 u_re: jax.Array, u_im: jax.Array,
+                 controls: tuple[int, ...] = ()) -> jax.Array:
+    """:func:`apply_gate_planar` for ``v`` lane qubits on a state of any
+    shape that flattens to ``(2, 2**n)``, returned in the view of its last
+    operation (see :func:`exchange`): the plan's program reshapes it once,
+    where the next item needs it."""
+    s = lane_window(n, v, tuple(qubits) + tuple(controls))
+    if s is not None:
+        data = exchange(data, n, 0, v, s)
+        mv = lambda bs: tuple(b + s if b < v else b for b in bs)
+        qubits, controls = mv(qubits), mv(controls)
+    out = _planar_rows(data, n, v, qubits, u_re, u_im, controls)
+    return out if s is None else exchange(out, n, 0, v, s)
 
 
 def apply_gate_planar(data: jax.Array, n: int, qubits: tuple[int, ...],
@@ -234,11 +258,8 @@ def apply_gate_planar(data: jax.Array, n: int, qubits: tuple[int, ...],
     the lane axis stays whole; the exchange is undone afterwards.
     """
     v = data.shape[-1].bit_length() - 1
-    data, qubits, controls, s = _lane_bits_out(data, n, v, qubits, controls)
-    out = _planar_rows(data, n, qubits, u_re, u_im, controls)
-    if s is not None:
-        out = swap_bits(out, n, 0, v, s)
-    return out
+    return apply_planar(data, n, v, qubits, u_re, u_im,
+                        controls).reshape(data.shape)
 
 
 def gate_arrays(g: Gate) -> tuple[jax.Array, jax.Array]:

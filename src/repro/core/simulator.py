@@ -24,6 +24,7 @@ from typing import Sequence
 import jax
 import numpy as np
 
+from repro.core import scopes
 from repro.core import statevec as SV
 from repro.core.circuits import Circuit
 from repro.core.fusion import choose_f, fuse_circuit
@@ -116,18 +117,25 @@ class Simulator:
         structure are single dispatches of the compiled program.  With
         ``mesh=`` set the program executes state-sharded over the devices
         (``CompiledPlan.run_sharded_batch_raw`` with a batch of one).
+
+        The call is the profiler span ``repro.sim.run``, the plan lookup
+        ``repro.plan.lookup`` inside it (:mod:`repro.core.scopes`).
         """
-        plan = self.plan_for(circuit)
-        spec = self._shard_spec(circuit.n)
-        if spec.is_single:
-            return plan.run(params=params, initial=initial)
-        if initial is not None:
-            raise ValueError("sharded runs build |0...0> on-device; "
-                             "initial states are not supported with mesh=")
-        pm = np.zeros((1, plan.num_params), np.float32) if params is None \
-            else np.asarray(params, np.float32).reshape(1, -1)
-        raw = plan.run_sharded_batch_raw(pm, self._mesh_for(spec))
-        return plan._wrap(raw[0])
+        with scopes.host_span("repro.sim.run", n=circuit.n):
+            with scopes.host_span("repro.plan.lookup"):
+                plan = self.plan_for(circuit)
+                spec = self._shard_spec(circuit.n)
+            if spec.is_single:
+                return plan.run(params=params, initial=initial)
+            if initial is not None:
+                raise ValueError("sharded runs build |0...0> on-device; "
+                                 "initial states are not supported with "
+                                 "mesh=")
+            pm = (np.zeros((1, plan.num_params), np.float32)
+                  if params is None
+                  else np.asarray(params, np.float32).reshape(1, -1))
+            raw = plan.run_sharded_batch_raw(pm, self._mesh_for(spec))
+            return plan._wrap(raw[0])
 
     # -- observables -----------------------------------------------------------
     def expectation_z(self, state: SV.State, qubit: int) -> jax.Array:

@@ -14,9 +14,9 @@ from repro.engine.plan import (  # noqa: F401
     resolve_diag_f, PARAM_OP_CLASS, GLOBAL_PLAN_CACHE,
 )
 from repro.engine.telemetry import (  # noqa: F401
-    Counter, Gauge, Histogram, MetricsRegistry, NULL_TRACER, ServedActivity,
-    Span, SpanTracer, VectorizationProfile, engine_registry,
-    vectorization_profile,
+    Histogram, MetricsRegistry, NULL_TRACER, ServedActivity, Span,
+    SpanTracer, VectorizationProfile, device_scope, engine_registry,
+    host_span, vectorization_profile,
 )
 from repro.engine.results import (  # noqa: F401
     MODE_EXPECTATION, MODE_NOISY, MODE_SHOTS, MODE_STATEVECTOR, NoiseChannel,

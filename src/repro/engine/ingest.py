@@ -51,7 +51,7 @@ from repro.engine.batch import BatchExecutor
 from repro.engine.results import ResultSpec
 from repro.engine.scheduler import (BatchScheduler, Request, validate_params,
                                     validate_sweep)
-from repro.engine.telemetry import STAGE_ENQUEUE
+from repro.engine.telemetry import STAGE_ENQUEUE, host_span
 from repro.engine.template import CircuitTemplate
 
 BLOCK = "block"      # producers wait for a pending slot (default)
@@ -332,7 +332,15 @@ class IngestServer:
         then resolves to int32 shot samples or f32 expectation values
         instead of a state.  Validated here so a bad spec (wrong type,
         out-of-range observable qubit) raises in the submitting thread,
-        mirroring the ``validate_params`` contract."""
+        mirroring the ``validate_params`` contract.  The call is the profiler
+        span ``repro.ingest.submit``."""
+        with host_span("repro.ingest.submit"):
+            return self._submit(template, params, timeout=timeout,
+                                deadline_ms=deadline_ms, result=result)
+
+    def _submit(self, template, params, *, timeout, deadline_ms,
+                result) -> IngestHandle:
+        """:meth:`submit` inside its span."""
         if deadline_ms is not None and deadline_ms <= 0:
             raise ValueError(f"deadline_ms must be > 0, got {deadline_ms}")
         if result is not None and not isinstance(result, ResultSpec):
@@ -470,23 +478,27 @@ class IngestServer:
         return len(resolved)
 
     def _step_once(self, force: bool = False) -> int:
-        """Ingest lanes -> poll the scheduler -> deliver results."""
+        """Ingest lanes -> poll the scheduler -> deliver results; the
+        profiler spans ``repro.ingest.collect``, ``repro.sched.poll`` and
+        ``repro.ingest.deliver``."""
         with self._sweep:
-            collected = self._collect()
-            # register BEFORE submitting: if an ingest raises mid-list,
-            # _abort can still fail every collected handle (never a silent
-            # drop)
-            for h in collected:
-                self._live[h.seq] = h
-            for h in collected:
-                h.request = self.scheduler.submit(h.template, h.params,
-                                                  deadline_at=h.deadline_at,
-                                                  result=h.result_spec)
-                if self.tracer.enabled and h.enqueue_ts is not None:
-                    self.tracer.record(h.request.req_id, STAGE_ENQUEUE,
-                                       h.enqueue_ts, seq=h.seq)
+            with host_span("repro.ingest.collect"):
+                collected = self._collect()
+                # register BEFORE submitting: if an ingest raises mid-list,
+                # _abort can still fail every collected handle (never a
+                # silent drop)
+                for h in collected:
+                    self._live[h.seq] = h
+                for h in collected:
+                    h.request = self.scheduler.submit(
+                        h.template, h.params, deadline_at=h.deadline_at,
+                        result=h.result_spec)
+                    if self.tracer.enabled and h.enqueue_ts is not None:
+                        self.tracer.record(h.request.req_id, STAGE_ENQUEUE,
+                                           h.enqueue_ts, seq=h.seq)
             self.scheduler.poll(force=force)
-            return self._deliver()
+            with host_span("repro.ingest.deliver"):
+                return self._deliver()
 
     def step(self, force: bool = False) -> int:
         """One deterministic drain iteration (no waiting, no thread).

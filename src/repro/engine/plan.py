@@ -26,6 +26,14 @@ arithmetic-intensity adaptation (§IV-D).  Plans compiled for a sharded mesh
 use the *local* row budget ``n - state_bits - lane_qubits``
 (:func:`repro.core.target.row_budget`), which is why plan-cache keys are
 mesh-shape-aware.
+
+Every operation a program emits carries device attributes
+(:mod:`repro.core.scopes`): each gate or channel item's operations
+``repro_item`` (its index in ``CompiledPlan.items``), ``repro_kind``,
+``repro_width`` and ``repro_part`` (``apply``, or ``exchange`` inside
+``core.apply.exchange``); the result epilogue's ``repro_kind="epilogue"``
+and ``repro_term``; the zero-state build ``repro_kind="init"``.  ``run``
+puts its host stages in the profiler trace as ``repro.*`` spans.
 """
 from __future__ import annotations
 
@@ -44,6 +52,7 @@ import numpy as np
 from repro.core import apply as A
 from repro.core import distributed as D
 from repro.core import measure as ME
+from repro.core import scopes
 from repro.core import statevec as SV
 from repro.core.circuits import Circuit
 from repro.core.fusion import choose_f, cluster_gates, realize_cluster
@@ -195,11 +204,11 @@ def _diag_planes(vec, layout):
 
 def _apply_phase(data, pr, pi, layout):
     """Rotate every amplitude of the planar state by the broadcast phase
-    table of :func:`_diag_planes` (6 real flops per amplitude)."""
+    table of :func:`_diag_planes` (6 real flops per amplitude); the result
+    is in the table's view ``(2, *layout[0])``."""
     t = data.reshape((2,) + layout[0])
     re, im = t[0], t[1]
-    return jnp.stack([pr * re - pi * im, pr * im + pi * re]
-                     ).reshape(data.shape)
+    return jnp.stack([pr * re - pi * im, pr * im + pi * re])
 
 
 def _member_monomial(g: Gate, full_qubits: tuple[int, ...],
@@ -551,12 +560,12 @@ def _flip_bits(data, n: int, v: int, qubits: tuple[int, ...]):
     lane axis stays whole."""
     s = A.lane_window(n, v, qubits)
     if s is not None:
-        data = A.swap_bits(data, n, 0, v, s)
+        data = A.exchange(data, n, 0, v, s)
         qubits = tuple(q + s if q < v else q for q in qubits)
     dims, axis = A.span_view(n, qubits)
     out = jnp.flip(data.reshape((2,) + dims),
-                   axis=[1 + axis[q] for q in qubits]).reshape(data.shape)
-    return out if s is None else A.swap_bits(out, n, 0, v, s)
+                   axis=[1 + axis[q] for q in qubits])
+    return out if s is None else A.exchange(out, n, 0, v, s)
 
 
 def _planar_special_step(item: PlanItem, n: int, v: int):
@@ -569,7 +578,9 @@ def _planar_special_step(item: PlanItem, n: int, v: int):
     onto physical positions with :func:`_relabel_special_item`).
     An XOR-mask permutation (X layers, composed bit flips) is an axis
     reversal (:func:`_flip_bits`); any other permutation is one static take
-    over the flat amplitude axis.
+    over the flat amplitude axis.  The step takes the state in any shape
+    that flattens to ``(2, 2**n)`` and returns it in the view of its last
+    operation (see ``repro.core.apply.exchange``).
     """
     layout = _diag_layout(item.qubits, n, v)
     has_phase = bool(item.phases)
@@ -591,7 +602,7 @@ def _planar_special_step(item: PlanItem, n: int, v: int):
         if flip_qs is not None:
             data = _flip_bits(data, n, v, flip_qs)
         elif src is not None:
-            data = data.reshape(2, -1)[:, src].reshape(data.shape)
+            data = data.reshape(2, -1)[:, src]
         if not has_phase:
             return data
         if const_phase is not None:
@@ -711,7 +722,7 @@ def _sharded_diag_step(item: PlanItem, phys: tuple[int, ...], n_local: int,
         gidx = jnp.asarray(base) + off
         pr = _diag_planes(jnp.take(pr_full, gidx), layout)
         pi = _diag_planes(jnp.take(pi_full, gidx), layout)
-        return _apply_phase(data, pr, pi, layout)
+        return _apply_phase(data, pr, pi, layout).reshape(data.shape)
     return step
 
 
@@ -809,6 +820,30 @@ def _restore_identity(data: jax.Array, perm: list[int], n: int,
                 rho_fix[perm[q]] = q
         data = _apply_local_bit_perm(data, tuple(rho_fix))
     return data, swaps
+
+
+def _tagged(step: Callable, index: int, item: PlanItem) -> Callable:
+    """``step`` with the operations it traces tagged as plan item
+    ``index`` (its position in ``CompiledPlan.items``); given ``shape``,
+    the result is reshaped to it inside the item's scope."""
+    def tagged(state, arg, shape=None):
+        with scopes.device_scope(item=index, kind=item.kind,
+                                 width=len(item.qubits), part="apply"):
+            out = step(state, arg)
+            return out if shape is None else out.reshape(shape)
+    return tagged
+
+
+def _run_steps(steps: list[Callable], state, arg, shape=None):
+    """Apply tagged ``steps`` in order.  Each returns the state in the view
+    of its last operation and the next reshapes it once; the last reshapes
+    it to ``shape``, or leaves it for a consumer that reshapes it itself.
+    (Two reshapes in a row are merged into one new reshape when the
+    program is converted to HLO, and that one keeps no device
+    attributes.)"""
+    for i, step in enumerate(steps):
+        state = step(state, arg, shape if i == len(steps) - 1 else None)
+    return state
 
 
 @functools.lru_cache(maxsize=4096)
@@ -914,7 +949,10 @@ class CompiledPlan:
 
     # -- program construction -------------------------------------------------
     def _step(self, item: PlanItem):
-        """Build the per-item closure for this plan's backend."""
+        """Build the per-item closure for this plan's backend.  It takes
+        the state in any shape that flattens to the plan's layout and
+        returns it in the view of its last operation (:func:`_run_steps`
+        reshapes it where needed)."""
         n = self.n
         if item.kind in ("diag", "perm"):
             return self._special_step(item)
@@ -923,21 +961,21 @@ class CompiledPlan:
                 return A.apply_gate_dense(psi, n, item.qubits,
                                           item.unitary(params), item.controls)
             return step
+        v = self.target.lane_qubits
         if self.backend == "planar":
             def step(data, params):
                 u = item.unitary(params)
-                return A.apply_gate_planar(
-                    data, n, item.qubits,
+                return A.apply_planar(
+                    data, n, v, item.qubits,
                     jnp.real(u).astype(jnp.float32),
                     jnp.imag(u).astype(jnp.float32), item.controls)
             return step
         from repro.kernels.apply_gate import ops as K
-        v = self.target.lane_qubits
         interpret = self.interpret
 
         def step(data, params):
             u = item.unitary(params)
-            return K.apply_fused_gate(
+            return K.fused_gate(
                 data, n, v, item.qubits,
                 jnp.real(u).astype(jnp.float32),
                 jnp.imag(u).astype(jnp.float32),
@@ -977,8 +1015,8 @@ class CompiledPlan:
                 p_re, p_im = item.phase_planes(params)
             else:
                 p_re = p_im = None
-            return K.apply_phase_gate(data, n, v, item.qubits, p_re, p_im,
-                                      perm=perm, interpret=interpret)
+            return K.phase_gate(data, n, v, item.qubits, p_re, p_im,
+                                perm=perm, interpret=interpret)
         return step
 
     def _gate_items(self) -> list[PlanItem]:
@@ -986,6 +1024,12 @@ class CompiledPlan:
         executed only by the result-mode program paths)."""
         return [it for it in self.items if it.kind in ("dense", "diag",
                                                        "perm")]
+
+    def _tagged_steps(self, items: list[PlanItem], build: Callable):
+        """``build(item)`` for each of ``items``, tagged with the item's
+        index in :attr:`items`."""
+        index = {id(it): i for i, it in enumerate(self.items)}
+        return [_tagged(build(it), index[id(it)], it) for it in items]
 
     def _program(self):
         """The ideal-circuit program ``(state, params) -> state``.
@@ -996,12 +1040,10 @@ class CompiledPlan:
         """
         if self.backend not in ("dense", "planar", "pallas"):
             raise ValueError(f"unknown backend {self.backend!r}")
-        steps = [self._step(item) for item in self._gate_items()]
+        steps = self._tagged_steps(self._gate_items(), self._step)
 
         def program(state, params):
-            for step in steps:
-                state = step(state, params)
-            return state
+            return _run_steps(steps, state, params, state.shape)
         return program
 
     def _params_array(self, params) -> jax.Array:
@@ -1017,7 +1059,8 @@ class CompiledPlan:
         if self.backend == "dense":
             if initial is not None:
                 return initial.to_dense()
-            return jnp.zeros(1 << self.n, jnp.complex64).at[0].set(1.0)
+            with scopes.device_scope(kind="init"):
+                return jnp.zeros(1 << self.n, jnp.complex64).at[0].set(1.0)
         if initial is not None:
             # the program is lowered for this plan's lane tiling; a state laid
             # out for another target must be re-tiled by the caller first
@@ -1028,7 +1071,8 @@ class CompiledPlan:
                     f"(v={self.target.lane_qubits}); convert via "
                     f"from_dense(state.to_dense(), n, target)")
             return initial.data
-        return SV.zero_state(self.n, self.target).data
+        with scopes.device_scope(kind="init"):
+            return SV.zero_state(self.n, self.target).data
 
     def _wrap(self, data) -> SV.State:
         if self.backend == "dense":
@@ -1055,13 +1099,18 @@ class CompiledPlan:
         with self._plock:
             if self._single is None:
                 self._single = jax.jit(self._program(), donate_argnums=(0,))
-        data0 = self._initial_data(initial)
-        if initial is not None:
-            data0 = jnp.array(data0)   # don't donate the caller's buffer
-        # lint-ok: EL001 _single is write-once under _plock above; this read
-        # happens after the build and the reference is never cleared, so the
-        # unlocked dispatch sees either this thread's or a prior build
-        out = self._single(data0, self._params_array(params))
+        with scopes.host_span("repro.state.init"):
+            data0 = self._initial_data(initial)
+            if initial is not None:
+                data0 = jnp.array(data0)   # don't donate the caller's buffer
+        with scopes.host_span("repro.params"):
+            p = self._params_array(params)
+        with scopes.host_span("repro.dispatch"):
+            # lint-ok: EL001 _single is write-once under _plock above; this
+            # read happens after the build and the reference is never
+            # cleared, so the unlocked dispatch sees either this thread's or
+            # a prior build
+            out = self._single(data0, p)
         return self._wrap(out)
 
     def run_batch_raw(self, params_matrix, initial: SV.State | None = None,
@@ -1171,7 +1220,8 @@ class CompiledPlan:
         return step
 
     def _observable_step(self, obs: tuple):
-        """Reduction ``(state) -> f32`` for one canonical Pauli string.
+        """Reduction ``(state) -> f32`` for one canonical Pauli string, on
+        the state in any shape that flattens to the plan's layout.
 
         pallas routes the single-qubit-Z case through the streaming
         expectation kernel (the paper's §IV reduction); everything else
@@ -1185,8 +1235,9 @@ class CompiledPlan:
             interpret = self.interpret
 
             def step(data):
-                return EXP.expectation_z(data, n, v, qubit,
-                                         interpret=interpret)
+                return EXP.expectation_z(
+                    data.reshape(2, 1 << (n - v), 1 << v), n, v, qubit,
+                    interpret=interpret)
             return step
         if self.backend == "dense":
             us = [(q, jnp.asarray(np.asarray(ME._PAULI[p], np.complex64)))
@@ -1202,10 +1253,12 @@ class CompiledPlan:
                    jnp.asarray(np.imag(ME._PAULI[p]).astype(np.float32)))
                   for q, p in obs]
 
+        v = self.target.lane_qubits
+
         def step(data):
             pd = data
             for q, ur, ui in planes:
-                pd = A.apply_gate_planar(pd, n, (q,), ur, ui)
+                pd = A.apply_planar(pd, n, v, (q,), ur, ui)
             a = data.reshape(2, -1)
             b = pd.reshape(2, -1)
             return jnp.sum(a[0] * b[0] + a[1] * b[1])
@@ -1218,14 +1271,21 @@ class CompiledPlan:
             shots = spec.shots
 
             def epi(data, key):
-                return ME.sample_probs(self._row_probs(data), shots,
-                                       jax.random.fold_in(key, _SHOT_SALT))
+                with scopes.device_scope(kind="epilogue"):
+                    return ME.sample_probs(self._row_probs(data), shots,
+                                           jax.random.fold_in(key,
+                                                              _SHOT_SALT))
             return epi
         # expectation / noisy: one reduction per observable, stacked
         steps = [self._observable_step(obs) for obs in spec.observables]
 
         def epi(data, key):
-            return jnp.stack([s(data) for s in steps]).astype(jnp.float32)
+            with scopes.device_scope(kind="epilogue"):
+                terms = []
+                for i, s in enumerate(steps):
+                    with scopes.device_scope(term=i):
+                        terms.append(s(data))
+                return jnp.stack(terms).astype(jnp.float32)
         return epi
 
     def _result_program(self):
@@ -1240,14 +1300,17 @@ class CompiledPlan:
         if spec is None:
             raise ValueError(f"{self.template.name}: plan has no result "
                              f"spec; use run/run_batch_raw")
-        steps = [self._step(it) for it in self._gate_items()]
-        chans = [self._channel_step(it) for it in self.items
-                 if it.kind == "channel"]
+        steps = self._tagged_steps(self._gate_items(), self._step)
+        chans = self._tagged_steps(
+            [it for it in self.items if it.kind == "channel"],
+            self._channel_step)
         epi = self._epilogue_step(spec)
 
         def program(state, params, rowkey):
-            for step in steps:
-                state = step(state, params)
+            # channel steps take the planar shape; the epilogue reshapes
+            # the state itself
+            state = _run_steps(steps, state, params,
+                               state.shape if chans else None)
             key = jax.random.fold_in(jax.random.PRNGKey(rowkey[0]),
                                      rowkey[1])
             for i, ch in enumerate(chans):
@@ -1344,8 +1407,9 @@ class CompiledPlan:
         if item.kind == "diag" and any(p >= n_local for p in phys):
             return _sharded_diag_step(item, phys, n_local, v)
         if item.kind in ("diag", "perm"):
-            return _planar_special_step(_relabel_special_item(item, phys),
+            step = _planar_special_step(_relabel_special_item(item, phys),
                                         n_local, v)
+            return lambda data, params: step(data, params).reshape(data.shape)
         local_ctrl = tuple(p for p in cphys if p < n_local)
         glob_ctrl = tuple(p for p in cphys if p >= n_local)
         return _sharded_dense_step(item, phys, local_ctrl, glob_ctrl, n_local)

@@ -68,7 +68,8 @@ from repro.engine.resilience import DeadlineExceeded, SITE_FINALIZE
 from repro.engine.results import MODE_STATEVECTOR, ResultSpec
 from repro.engine.telemetry import (Histogram, NULL_TRACER, STAGE_DEVICE_READY,
                                     STAGE_DISPATCH, STAGE_DONE, STAGE_FAILED,
-                                    STAGE_RETRYING, STAGE_SHED, STAGE_SUBMIT)
+                                    STAGE_RETRYING, STAGE_SHED, STAGE_SUBMIT,
+                                    host_span)
 from repro.engine.template import CircuitTemplate, template_of
 
 # retained latency samples for percentile estimates; totals stay exact
@@ -235,6 +236,43 @@ def _pad_size(b: int, max_batch: int) -> int:
 _FILLER_ROWKEY = 0xFFFFFFFF
 
 
+def _stage(chunk: list[Request], rows: list[int] | None, klass: bool,
+           padded: int) -> tuple:
+    """``(params matrix, rowkeys, templates)`` of one chunk, padded to
+    ``padded`` device rows.
+
+    A noisy request's ``rows[i]`` trajectories are each stamped with
+    (request key, trajectory index): randomness never depends on batch
+    position.  Filler rows are inert: zero params and a dead rowkey — a
+    padded slot must never re-execute a real request's sampling epilogue
+    (replicating the last row would re-run its full unraveling, and its
+    payload would differ from the real row's only by being discarded —
+    wasted flops and a misleading trace).  ``templates`` (per row) only
+    for a shape-class chunk."""
+    if rows is None:
+        pm = np.stack([r.params for r in chunk])
+        rowkeys = None
+        templates = [r.template for r in chunk] if klass else None
+    else:
+        pm = np.concatenate([np.repeat(r.params[None, :], k, axis=0)
+                             for r, k in zip(chunk, rows)])
+        rowkeys = np.concatenate([
+            np.stack([np.full(k, r.result_spec.key, np.uint32),
+                      np.arange(k, dtype=np.uint32)], axis=1)
+            for r, k in zip(chunk, rows)])
+        templates = ([r.template for r, k in zip(chunk, rows)
+                      for _ in range(k)] if klass else None)
+    b = pm.shape[0]
+    if padded > b:
+        pm = np.concatenate(
+            [pm, np.zeros((padded - b, pm.shape[1]), np.float32)])
+        if rowkeys is not None:
+            rowkeys = np.concatenate(
+                [rowkeys, np.full((padded - b, 2), _FILLER_ROWKEY,
+                                  np.uint32)])
+    return pm, rowkeys, templates
+
+
 @dataclasses.dataclass
 class SchedulerStats:
     """Aggregate serving counters, safe under concurrent submitters.
@@ -398,10 +436,11 @@ class InFlightBatch:
                  stats: SchedulerStats,
                  clock: Callable[[], float] = time.perf_counter,
                  tracer=NULL_TRACER, scheduler=None, injector=None,
-                 rows: list[int] | None = None):
+                 rows: list[int] | None = None, padded: int = 0):
         self.plan = plan
         self.requests = requests
         self.rows = rows                 # per-request row counts (result mode)
+        self.padded = padded             # device rows, filler included
         self.raw = raw                   # unwaited device array [padded, ...]
         self.stats = stats
         self.clock = clock
@@ -432,53 +471,63 @@ class InFlightBatch:
             return True
 
     def finalize(self) -> None:
-        """Wait for device results and retire every request (idempotent)."""
+        """Wait for device results and retire every request (idempotent);
+        the profiler span ``repro.sched.finalize``."""
         with self._flock:
             if self.finalized:
                 return
             self.finalized = True
-            try:
-                if self.injector is not None:
-                    self.injector.fire(SITE_FINALIZE)
-                jax.block_until_ready(self.raw)
-            except Exception as e:  # noqa: BLE001 — device-side failure
-                self.raw = None
-                if self.scheduler is not None:
-                    # retry-aware path: transient faults re-enqueue the
-                    # whole chunk; budget-exhausted requests finalize FAILED
-                    self.scheduler._resolve_batch_failure(self.requests, e)
-                else:
-                    _fail(self.requests, e, self.stats, self.clock(),
-                          tracer=self.tracer)
-                return
-            now = self.clock()
-            if self.plan.result is not None:
-                # non-statevector payloads: collapse row expansion (noisy
-                # trajectories average) back to one payload per request
-                results = _reduce_result_rows(
-                    np.asarray(self.raw),
-                    self.rows if self.rows is not None
-                    else [1] * len(self.requests))
-            else:
-                results = self.plan.wrap_batch(self.raw,
-                                               count=len(self.requests))
-            for req, res in zip(self.requests, results):
-                req.result = res
-                req.latency = now - req.submitted
-                req._transition(RequestState.DONE)
-                self.stats.add_latency(req.latency)
+            rows = len(self.requests) if self.rows is None else sum(self.rows)
+            with host_span("repro.sched.finalize", rows=rows,
+                           padded=self.padded,
+                           req=self.requests[0].req_id):
+                self._retire()
+
+    def _retire(self) -> None:
+        """Wait for device results and retire every request.  Caller holds
+        ``_flock``."""
+        try:
+            if self.injector is not None:
+                self.injector.fire(SITE_FINALIZE)
+            jax.block_until_ready(self.raw)
+        except Exception as e:  # noqa: BLE001 — device-side failure
             self.raw = None
             if self.scheduler is not None:
-                # a success resets the plan breaker's consecutive-failure
-                # count for this chunk's key
-                self.scheduler._note_outcome(self.requests, ok=True)
-            if self.tracer.enabled:
-                # device retire at ``now`` (the latency stamp), finalize —
-                # host-side wrap + lifecycle transitions — ends here
-                end = self.clock()
-                for req in self.requests:
-                    self.tracer.record(req.req_id, STAGE_DEVICE_READY, now)
-                    self.tracer.record(req.req_id, STAGE_DONE, end)
+                # retry-aware path: transient faults re-enqueue the
+                # whole chunk; budget-exhausted requests finalize FAILED
+                self.scheduler._resolve_batch_failure(self.requests, e)
+            else:
+                _fail(self.requests, e, self.stats, self.clock(),
+                      tracer=self.tracer)
+            return
+        now = self.clock()
+        if self.plan.result is not None:
+            # non-statevector payloads: collapse row expansion (noisy
+            # trajectories average) back to one payload per request
+            results = _reduce_result_rows(
+                np.asarray(self.raw),
+                self.rows if self.rows is not None
+                else [1] * len(self.requests))
+        else:
+            results = self.plan.wrap_batch(self.raw,
+                                           count=len(self.requests))
+        for req, res in zip(self.requests, results):
+            req.result = res
+            req.latency = now - req.submitted
+            req._transition(RequestState.DONE)
+            self.stats.add_latency(req.latency)
+        self.raw = None
+        if self.scheduler is not None:
+            # a success resets the plan breaker's consecutive-failure
+            # count for this chunk's key
+            self.scheduler._note_outcome(self.requests, ok=True)
+        if self.tracer.enabled:
+            # device retire at ``now`` (the latency stamp), finalize —
+            # host-side wrap + lifecycle transitions — ends here
+            end = self.clock()
+            for req in self.requests:
+                self.tracer.record(req.req_id, STAGE_DEVICE_READY, now)
+                self.tracer.record(req.req_id, STAGE_DONE, end)
 
 
 def _reduce_result_rows(arr: np.ndarray, rows: list[int]) -> list[np.ndarray]:
@@ -992,24 +1041,11 @@ class BatchScheduler:
         # key-uniform chunk always takes the exact path (identical results,
         # and the per-plan program is already the hot one)
         klass = len({r._key for r in chunk}) > 1
-        if spec is None:
-            pm = np.stack([r.params for r in chunk])
-            rowkeys = rows = None
-            templates = [r.template for r in chunk] if klass else None
-        else:
-            # row expansion: a noisy request occupies ``unravelings`` rows
-            # of the vmapped batch axis, each stamped with (request key,
-            # trajectory index) — randomness never depends on batch position
-            rows = [r.result_spec.rows for r in chunk]
-            pm = np.concatenate([np.repeat(r.params[None, :], k, axis=0)
-                                 for r, k in zip(chunk, rows)])
-            rowkeys = np.concatenate([
-                np.stack([np.full(k, r.result_spec.key, np.uint32),
-                          np.arange(k, dtype=np.uint32)], axis=1)
-                for r, k in zip(chunk, rows)])
-            templates = ([r.template for r, k in zip(chunk, rows)
-                          for _ in range(k)] if klass else None)
-        b = pm.shape[0]
+        # a noisy request occupies ``unravelings`` rows of the vmapped batch
+        # axis (row expansion)
+        rows = (None if spec is None
+                else [r.result_spec.rows for r in chunk])
+        b = len(chunk) if rows is None else sum(rows)
         if not self.pad_to_pow2:
             padded = b
         elif b <= self.max_batch:
@@ -1019,26 +1055,17 @@ class BatchScheduler:
             # chunking dispatches it alone): pad to the next power of two so
             # oversized traffic still compiles O(log) distinct batch sizes
             padded = 1 << (b - 1).bit_length()
-        if padded > b:
-            # inert filler rows: zero params and a dead rowkey — a padded
-            # slot must never re-execute a real request's sampling epilogue
-            # (replicating the last row would re-run its full unraveling,
-            # and its payload would differ from the real row's only by
-            # being discarded — wasted flops and a misleading trace)
-            pm = np.concatenate(
-                [pm, np.zeros((padded - b, pm.shape[1]), np.float32)])
-            if rowkeys is not None:
-                rowkeys = np.concatenate(
-                    [rowkeys, np.full((padded - b, 2), _FILLER_ROWKEY,
-                                      np.uint32)])
+        span = {"rows": b, "padded": padded, "req": chunk[0].req_id}
+        with host_span("repro.sched.stage", **span):
+            pm, rowkeys, templates = _stage(chunk, rows, klass, padded)
         try:
-            if klass:
-                plan, raw = self.executor.dispatch_class_batch(
-                    templates, pm, result=spec, rowkeys=rowkeys)
-            else:
-                plan, raw = self.executor.dispatch_batch(template, pm,
-                                                         result=spec,
-                                                         rowkeys=rowkeys)
+            with host_span("repro.sched.dispatch", **span):
+                if klass:
+                    plan, raw = self.executor.dispatch_class_batch(
+                        templates, pm, result=spec, rowkeys=rowkeys)
+                else:
+                    plan, raw = self.executor.dispatch_batch(
+                        template, pm, result=spec, rowkeys=rowkeys)
         except Exception as e:  # noqa: BLE001 — compile/trace/launch failure
             self._resolve_batch_failure(chunk, e)
             return None
@@ -1052,7 +1079,7 @@ class BatchScheduler:
         injector = getattr(self.executor, "injector", None)
         batch = InFlightBatch(plan, chunk, raw, self.stats, clock=self._clock,
                               tracer=self.tracer, scheduler=self,
-                              injector=injector, rows=rows)
+                              injector=injector, rows=rows, padded=padded)
         if injector is not None:
             batch.straggler = injector.draw_straggler()
         overflow: list[InFlightBatch] = []
@@ -1075,19 +1102,22 @@ class BatchScheduler:
         in-flight batch whose device results are already available
         (``InFlightBatch.ready``), oldest first.  Never blocks on the
         device: a batch still executing stays in the window.  Returns the
-        newly launched batches.
+        newly launched batches.  The call is the profiler span
+        ``repro.sched.poll``; the batches it stages, dispatches and
+        finalizes are spans inside it.
         """
         launched: list[InFlightBatch] = []
-        for reqs in self._take_retries(force):
-            launched += self._dispatch_group(reqs)
-        for reqs in self._take_triggered(force):
-            launched += self._dispatch_group(reqs)
-        while True:
-            with self._lock:
-                if not (self._window and self._window[0].ready):
-                    break
-                batch = self._window.popleft()
-            batch.finalize()
+        with host_span("repro.sched.poll"):
+            for reqs in self._take_retries(force):
+                launched += self._dispatch_group(reqs)
+            for reqs in self._take_triggered(force):
+                launched += self._dispatch_group(reqs)
+            while True:
+                with self._lock:
+                    if not (self._window and self._window[0].ready):
+                        break
+                    batch = self._window.popleft()
+                batch.finalize()
         return launched
 
     def retire_one(self) -> bool:

@@ -211,7 +211,8 @@ def _special_class_step(item, n: int, v: int, slot0: int):
                     cr, ci = consts[pr_slot], consts[pi_slot]
                     pr, pi = c * cr - sn * ci, c * ci + sn * cr
             data = _apply_phase(data, _diag_planes(pr, layout),
-                                _diag_planes(pi, layout), layout)
+                                _diag_planes(pi, layout),
+                                layout).reshape(data.shape)
         return data
     return step, s
 

@@ -1,20 +1,21 @@
-"""End-to-end engine telemetry: metrics registry, span tracing, activity.
+"""End-to-end engine telemetry: metrics registry, span tracing, activity,
+and the names the profiler sees.
 
 The paper's methodological contribution beyond raw speedups is *measurement*:
 it defines PMU-derived metrics (AVL, IRR — §VII-A) to quantify vectorization
 activity and uses them to explain performance across machines.  This module
-is the serving-side analogue, three instruments sharing one clock discipline:
+is the serving-side analogue, four instruments:
 
-* **Metrics registry** — thread-safe counters, gauges, and *bounded*
-  histograms (fixed memory: exact count/sum/min/max forever, percentiles
-  over a fixed-capacity window of the most recent samples).
-  :class:`MetricsRegistry` unifies the engine's scattered stats objects
-  (``SchedulerStats``, ``CacheStats``, the ingest counters, served
-  vectorization activity) behind one ``snapshot()`` / ``write_json()``
-  API via *sources* — callables polled at snapshot time, so the existing
-  lock-carrying stats objects stay the single writers of their counters
-  (exactness under the 8-producer hammer is theirs; the registry never
-  copies a counter it could race).
+* **Metrics registry** — :class:`MetricsRegistry` unifies the engine's
+  scattered stats objects (``SchedulerStats``, ``CacheStats``, the ingest
+  counters, served vectorization activity) behind one ``snapshot()`` /
+  ``write_json()`` API via *sources* — callables polled at snapshot time,
+  so the existing lock-carrying stats objects stay the single writers of
+  their counters (exactness under the 8-producer hammer is theirs; the
+  registry never copies a counter it could race).  :class:`Histogram` is
+  the bounded sample record those stats objects keep (fixed memory: exact
+  count/sum/min/max forever, percentiles over a fixed-capacity window of
+  the most recent samples).
 
 * **Span tracing** — :class:`SpanTracer` records per-request lifecycle
   events (ingest lane enqueue → scheduler submit → dispatch → device
@@ -36,6 +37,18 @@ is the serving-side analogue, three instruments sharing one clock discipline:
   plan key, so a running server can report "what fraction of served
   amplitudes took the diagonal fast path, at what lane occupancy" — the
   serving-side analogue of the paper's Table IV.
+
+* **Profiler names** — :func:`device_scope` and :func:`host_span`
+  (defined in :mod:`repro.core.scopes`, so that ``core`` and the kernels
+  can use them without importing the engine).  Every operation of a
+  compiled plan program carries ``repro_*`` device attributes (plan item,
+  kind, width, exchange or apply, epilogue term), which reach a TPU
+  profile; the program's host stages are ``repro.*`` spans on the
+  profiler's own clock, where ``SpanTracer``'s scheduler-clock stamps
+  cannot go.  The attributes leave the compiled program as it was; a
+  span costs about a microsecond of host time when no profile is being
+  taken (``docs/OBSERVABILITY.md``, "Device attributes and profiler
+  spans").
 """
 from __future__ import annotations
 
@@ -47,9 +60,10 @@ from typing import Callable, Sequence
 import numpy as np
 
 from repro.core.metrics import circuit_cost
+from repro.core.scopes import device_scope, host_span
 
 __all__ = [
-    "Counter", "Gauge", "Histogram", "MetricsRegistry",
+    "Histogram", "MetricsRegistry", "device_scope", "host_span",
     "Span", "SpanTracer", "NULL_TRACER",
     "STAGE_ENQUEUE", "STAGE_SUBMIT", "STAGE_DISPATCH",
     "STAGE_DEVICE_READY", "STAGE_DONE", "STAGE_FAILED",
@@ -60,52 +74,6 @@ __all__ = [
 
 
 # -- instruments ---------------------------------------------------------------
-
-class Counter:
-    """Monotonic counter, exact under concurrent writers."""
-
-    __slots__ = ("name", "_value", "_lock")
-
-    def __init__(self, name: str):
-        self.name = name
-        self._value = 0                     #: guarded-by: _lock
-        self._lock = threading.Lock()
-
-    def inc(self, k: int = 1) -> None:
-        with self._lock:
-            self._value += k
-
-    @property
-    def value(self) -> int:
-        with self._lock:
-            return self._value
-
-    def __repr__(self) -> str:
-        return f"Counter({self.name}={self.value})"
-
-
-class Gauge:
-    """Last-write-wins instantaneous value."""
-
-    __slots__ = ("name", "_value", "_lock")
-
-    def __init__(self, name: str):
-        self.name = name
-        self._value = 0.0                   #: guarded-by: _lock
-        self._lock = threading.Lock()
-
-    def set(self, v: float) -> None:
-        with self._lock:
-            self._value = float(v)
-
-    @property
-    def value(self) -> float:
-        with self._lock:
-            return self._value
-
-    def __repr__(self) -> str:
-        return f"Gauge({self.name}={self.value})"
-
 
 class Histogram:
     """Bounded-memory sample histogram with exact totals.
@@ -185,42 +153,18 @@ class Histogram:
 
 
 class MetricsRegistry:
-    """Create-or-get instrument registry plus pollable snapshot sources.
+    """Pollable snapshot sources behind one export API.
 
-    Instruments (:meth:`counter` / :meth:`gauge` / :meth:`histogram`) are
-    owned by the registry and keyed by name — asking twice returns the same
-    object, asking with a different type raises.  *Sources* are callables
-    returning dicts, polled at :meth:`snapshot` time and merged under a
-    prefix; they let the engine's existing lock-carrying stats objects
-    (``SchedulerStats``, ``CacheStats``, ingest counters, served activity)
-    publish through one export API without a second copy of their state.
+    *Sources* are callables returning dicts, polled at :meth:`snapshot`
+    time and merged under a prefix; they let the engine's existing
+    lock-carrying stats objects (``SchedulerStats``, ``CacheStats``,
+    ingest counters, served activity) publish through one export API
+    without a second copy of their state.
     """
 
     def __init__(self):
         self._lock = threading.Lock()
-        self._instruments: dict[str, object] = {}  #: guarded-by: _lock
         self._sources: list[tuple[str, Callable[[], dict]]] = []  #: guarded-by: _lock
-
-    def _get(self, name: str, cls, factory):
-        with self._lock:
-            inst = self._instruments.get(name)
-            if inst is None:
-                inst = self._instruments[name] = factory()
-            elif not isinstance(inst, cls):
-                raise TypeError(
-                    f"metric {name!r} already registered as "
-                    f"{type(inst).__name__}, not {cls.__name__}")
-            return inst
-
-    def counter(self, name: str) -> Counter:
-        return self._get(name, Counter, lambda: Counter(name))
-
-    def gauge(self, name: str) -> Gauge:
-        return self._get(name, Gauge, lambda: Gauge(name))
-
-    def histogram(self, name: str, capacity: int = 4096) -> Histogram:
-        return self._get(name, Histogram,
-                         lambda: Histogram(capacity, name=name))
 
     def register_source(self, prefix: str, fn: Callable[[], dict]) -> None:
         """Attach a dict-returning callable; its keys appear in snapshots
@@ -230,19 +174,10 @@ class MetricsRegistry:
             self._sources.append((prefix, fn))
 
     def snapshot(self) -> dict:
-        """One flat dict over every instrument and source.  Histograms
-        expand to ``<name>_count/_mean/_p50/_p95/_p99/_max`` (omitted
-        entirely while empty)."""
+        """One flat dict over every source."""
         with self._lock:
-            instruments = list(self._instruments.values())
             sources = list(self._sources)
         out: dict = {}
-        for inst in instruments:
-            if isinstance(inst, Histogram):
-                out.update({f"{inst.name}_{k}": v
-                            for k, v in inst.summary().items()})
-            else:
-                out[inst.name] = inst.value
         for prefix, fn in sources:
             for k, v in fn().items():
                 out[f"{prefix}_{k}"] = v

@@ -26,6 +26,11 @@ Callers move gate bits that fall inside the ``(8, V)`` tile out of it first
 Phase kernel (diagonal clusters): every marked bit above the tile is a grid
 axis, so a block needs one row of the phase table; cluster bits inside the
 tile are folded into that row, which is laid out as a whole ``(8, V)`` tile.
+
+Each ``pallas_call`` has a stable ``name`` (``fused_gate``, ``phase``) and
+``metadata`` naming the plan item and kind it runs for
+(:func:`repro.core.scopes.kernel_metadata`), so a profile attributes every
+call; the engine lint's EL006 keeps both on every kernel.
 """
 from __future__ import annotations
 
@@ -38,6 +43,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+
+from repro.core import scopes
 
 HIGHEST = jax.lax.Precision.HIGHEST
 SUBLANES = 8
@@ -157,23 +164,25 @@ def _kernel(u_re_ref, u_im_ref, x_ref, o_ref, *, plan: ViewPlan):
         o_ref[...] = x_ref[...]
 
 
-def apply_fused_gate_kernel(data_flat: jax.Array, u_re: jax.Array,
+def apply_fused_gate_kernel(data: jax.Array, u_re: jax.Array,
                             u_im: jax.Array, plan: ViewPlan,
                             interpret: bool) -> jax.Array:
-    """Run the dense kernel on the flat planar state f32[2, 2**n]."""
-    shaped = data_flat.reshape((2,) + plan.dims)
+    """Run the dense kernel on the planar state (any shape that flattens to
+    f32[2, 2**n]); the result is in the kernel's view ``(2, *plan.dims)``."""
+    shaped = data.reshape((2,) + plan.dims)
     spec = _state_spec(plan)
     dim = u_re.shape[0]
     u_spec = pl.BlockSpec((dim, dim), lambda g: (0, 0))
-    out = pl.pallas_call(
+    return pl.pallas_call(
         functools.partial(_kernel, plan=plan),
         grid=(plan.grid,),
         in_specs=[u_spec, u_spec, spec],
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct(shaped.shape, jnp.float32),
         interpret=interpret,
+        name="fused_gate",
+        metadata=scopes.kernel_metadata(),
     )(u_re, u_im, shaped)
-    return out.reshape(data_flat.shape)
 
 
 def _phase_kernel(p_ref, x_ref, o_ref, *, tile_rows: int):
@@ -203,11 +212,12 @@ def phase_tile_map(qubits: Sequence[int], tile_bits: int) -> np.ndarray:
     return out.astype(np.int32)
 
 
-def apply_phase_kernel(data_flat: jax.Array, table: jax.Array,
+def apply_phase_kernel(data: jax.Array, table: jax.Array,
                        hi_bits: Sequence[int], n: int, tile_rows: int,
                        lanes: int, interpret: bool,
                        max_block_bytes: int = 1 << 20) -> jax.Array:
-    """Run the phase kernel on the flat planar state f32[2, 2**n].
+    """Run the phase kernel on the planar state (any shape that flattens to
+    f32[2, 2**n]); the result is in the kernel's view.
 
     ``table`` is ``f32[2, 2**len(hi_bits) * tile_rows, lanes]``: for each
     pattern of the cluster bits above the tile (``hi_bits``, sorted; bit
@@ -216,7 +226,7 @@ def apply_phase_kernel(data_flat: jax.Array, table: jax.Array,
     """
     plan = make_plan(n, (), tuple(hi_bits), max_block_bytes=max_block_bytes,
                      lanes=lanes)
-    shaped = data_flat.reshape((2,) + plan.dims)
+    shaped = data.reshape((2,) + plan.dims)
     hi_axes = [i for i, r in enumerate(plan.roles) if r == "ctrl"]
     # hi axes come MSB first: axis j holds hi bit len-1-j
     weights = [1 << (len(hi_axes) - 1 - j) for j in range(len(hi_axes))]
@@ -229,12 +239,13 @@ def apply_phase_kernel(data_flat: jax.Array, table: jax.Array,
         return (0, row, 0)
 
     spec = _state_spec(plan)
-    out = pl.pallas_call(
+    return pl.pallas_call(
         functools.partial(_phase_kernel, tile_rows=tile_rows),
         grid=(plan.grid,),
         in_specs=[pl.BlockSpec((2, tile_rows, lanes), table_map), spec],
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct(shaped.shape, jnp.float32),
         interpret=interpret,
+        name="phase",
+        metadata=scopes.kernel_metadata(),
     )(table, shaped)
-    return out.reshape(data_flat.shape)

@@ -7,7 +7,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.apply import swap_bits
+from repro.core.apply import exchange
 from repro.core.target import resolve_interpret
 from repro.kernels.apply_gate.apply_gate import (
     SUBLANES, apply_fused_gate_kernel, apply_phase_kernel, make_plan,
@@ -83,6 +83,18 @@ def apply_fused_gate(data: jax.Array, n: int, v: int,
     free high bits (one transpose per lane/sublane block, undone after), so
     the kernel's tail axes are whole tiles.
     """
+    return fused_gate(data, n, v, qubits, u_re, u_im, controls, interpret,
+                      max_block_bytes).reshape(data.shape)
+
+
+def fused_gate(data: jax.Array, n: int, v: int, qubits: tuple[int, ...],
+               u_re: jax.Array, u_im: jax.Array,
+               controls: tuple[int, ...] = (), interpret: bool | None = None,
+               max_block_bytes: int = 1 << 20) -> jax.Array:
+    """:func:`apply_fused_gate` on a state of any shape that flattens to
+    ``(2, 2**n)``, returned in the view of its last operation, so that
+    no two reshapes follow each other (see ``repro.core.apply.exchange``).
+    """
     interpret = resolve_interpret(interpret)
     swaps = tile_swaps(n, v, tuple(qubits) + tuple(controls)) or ()
     qubits, controls = _moved(qubits, swaps), _moved(controls, swaps)
@@ -93,15 +105,14 @@ def apply_fused_gate(data: jax.Array, n: int, v: int,
         u_im = u_im[p][:, p]
     plan = make_plan(n, qs_sorted, tuple(sorted(controls)),
                      max_block_bytes=max_block_bytes, lanes=1 << v)
-    flat = data.reshape(2, 1 << n)
     for sw in swaps:
-        flat = swap_bits(flat, n, *sw)
-    out = apply_fused_gate_kernel(flat, jnp.asarray(u_re, jnp.float32),
+        data = exchange(data, n, *sw)
+    out = apply_fused_gate_kernel(data, jnp.asarray(u_re, jnp.float32),
                                   jnp.asarray(u_im, jnp.float32), plan,
                                   interpret=interpret)
     for sw in reversed(swaps):
-        out = swap_bits(out, n, *sw)
-    return out.reshape(data.shape)
+        out = exchange(out, n, *sw)
+    return out
 
 
 def apply_phase_gate(data: jax.Array, n: int, v: int,
@@ -124,6 +135,17 @@ def apply_phase_gate(data: jax.Array, n: int, v: int,
     select a row of the phase table, and the bits inside it are spread
     over that row as a whole ``(8, V)`` tile.
     """
+    return phase_gate(data, n, v, qubits, p_re, p_im, perm, interpret,
+                      max_block_bytes).reshape(data.shape)
+
+
+def phase_gate(data: jax.Array, n: int, v: int, qubits: tuple[int, ...],
+               p_re: jax.Array | None, p_im: jax.Array | None, perm=None,
+               interpret: bool | None = None,
+               max_block_bytes: int = 1 << 20) -> jax.Array:
+    """:func:`apply_phase_gate` on a state of any shape that flattens to
+    ``(2, 2**n)``, returned in the view of its last operation (see
+    :func:`fused_gate`)."""
     qubits = tuple(qubits)
     if qubits != tuple(sorted(qubits)):
         raise ValueError(f"apply_phase_gate needs sorted qubits, got {qubits}")
@@ -135,9 +157,9 @@ def apply_phase_gate(data: jax.Array, n: int, v: int,
         cols = jnp.asarray(np.asarray(perm), jnp.int32)
         u_re = jnp.zeros((dim, dim), jnp.float32).at[rows, cols].set(p_re)
         u_im = jnp.zeros((dim, dim), jnp.float32).at[rows, cols].set(p_im)
-        return apply_fused_gate(data, n, v, qubits, u_re, u_im,
-                                interpret=interpret,
-                                max_block_bytes=max_block_bytes)
+        return fused_gate(data, n, v, qubits, u_re, u_im,
+                          interpret=interpret,
+                          max_block_bytes=max_block_bytes)
     interpret = resolve_interpret(interpret)
     t, tile_rows = _tile_bits(n, v)
     hi = tuple(q for q in qubits if q >= t)
@@ -149,10 +171,9 @@ def apply_phase_gate(data: jax.Array, n: int, v: int,
 
     tab = jnp.stack([table(jnp.asarray(p_re, jnp.float32)),
                      table(jnp.asarray(p_im, jnp.float32))])
-    out = apply_phase_kernel(data.reshape(2, 1 << n), tab, hi, n, tile_rows,
-                             1 << v, interpret=interpret,
-                             max_block_bytes=max_block_bytes)
-    return out.reshape(data.shape)
+    return apply_phase_kernel(data, tab, hi, n, tile_rows, 1 << v,
+                              interpret=interpret,
+                              max_block_bytes=max_block_bytes)
 
 
 def apply_circuit(data: jax.Array, n: int, v: int, gates,

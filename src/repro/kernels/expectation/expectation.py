@@ -15,6 +15,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.core import scopes
 from repro.kernels.apply_gate.apply_gate import SUBLANES
 
 
@@ -55,5 +56,7 @@ def expectation_z_kernel(data: jax.Array, qubit: int, interpret: bool,
         out_specs=pl.BlockSpec((acc_rows, lanes), lambda g: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((acc_rows, lanes), jnp.float32),
         interpret=interpret,
+        name="expectation_z",
+        metadata=scopes.kernel_metadata(),
     )(data)
     return jnp.sum(out)

@@ -484,6 +484,31 @@ def test_el005_offending_and_conforming():
     assert lint_source(offending, ENGINE_PATH) == []
 
 
+KERNEL_PATH = "src/repro/kernels/fixture/fixture.py"
+
+
+def test_el006_kernels_name_their_pallas_calls():
+    """A planted kernel that drops out of the attribution is found."""
+    offending = ("from jax.experimental import pallas as pl\n"
+                 "def run(x, k):\n"
+                 "    a = pl.pallas_call(k, out_shape=x, grid=(1,))(x)\n"
+                 "    return pl.pallas_call(k, out_shape=x, name='k')(a)\n")
+    found = lint_source(offending, KERNEL_PATH)
+    assert _codes(found) == ["EL006", "EL006"]
+    assert "name and metadata" in found[0].message
+    assert "without metadata" in found[1].message
+
+    conforming = ("from jax.experimental import pallas as pl\n"
+                  "from repro.core import scopes\n"
+                  "def run(x, k):\n"
+                  "    return pl.pallas_call(\n"
+                  "        k, out_shape=x, name='k',\n"
+                  "        metadata=scopes.kernel_metadata())(x)\n")
+    assert lint_source(conforming, KERNEL_PATH) == []
+    # kernels-only rule
+    assert lint_source(offending, ENGINE_PATH) == []
+
+
 def test_syntax_rule():
     found = lint_source("def broken(:\n", "tools/fixture.py")
     assert _codes(found) == ["SYNTAX"]

@@ -59,38 +59,19 @@ def test_histogram_empty_and_validation():
 
 
 def test_registry_create_or_get_and_type_conflicts():
+    """The registry is its sources: each is polled at snapshot time and
+    its keys appear under its prefix, so a snapshot always reads the
+    sources' current values and never a copy."""
     reg = MetricsRegistry()
-    c = reg.counter("x")
-    assert reg.counter("x") is c
-    with pytest.raises(TypeError, match="already registered"):
-        reg.gauge("x")
-    reg.gauge("g").set(2.5)
-    reg.histogram("h").record(1.0)
-    snap = reg.snapshot()
-    assert snap["g"] == 2.5 and snap["h_count"] == 1
-    reg.register_source("src", lambda: {"k": 7})
-    assert reg.snapshot()["src_k"] == 7
-
-
-@pytest.mark.timeout(120)
-def test_registry_exact_under_8_hammering_threads():
-    """Counters and histogram totals lose nothing under 8 barrier-synced
-    writers — the same exactness bar the scheduler stats are held to."""
-    reg = MetricsRegistry()
-    per_thread = 500
-
-    def hammer(i: int):
-        c = reg.counter("events")             # create-or-get race included
-        h = reg.histogram("lat", capacity=64)
-        for j in range(per_thread):
-            c.inc()
-            h.record(float(j))
-        return per_thread
-
-    run_producers(8, hammer)
-    assert reg.counter("events").value == 8 * per_thread
-    assert len(reg.histogram("lat")) == 8 * per_thread
-    assert reg.snapshot()["events"] == 8 * per_thread
+    assert reg.snapshot() == {}
+    state = {"k": 7}
+    reg.register_source("src", lambda: dict(state))
+    reg.register_source("other", lambda: {"k": "x", "n": 1})
+    assert reg.snapshot() == {"src_k": 7, "other_k": "x", "other_n": 1}
+    state["k"] = 8
+    assert reg.snapshot()["src_k"] == 8
+    for gone in ("counter", "gauge", "histogram"):
+        assert not hasattr(reg, gone)
 
 
 def test_scheduler_stats_latencies_bounded():
