@@ -55,11 +55,13 @@ def device_scope(**attrs):
         _local.attrs = outer
 
 
-def kernel_metadata() -> dict[str, str]:
+def kernel_metadata(**extra) -> dict[str, str]:
     """``pallas_call(metadata=...)`` for a kernel launched inside the
-    current scope: its plan item and kind."""
+    current scope: its plan item and kind, and the kernel's own ``extra``
+    keys (the phase kernel's ``steps`` and ``block_bytes``)."""
     attrs = current_attrs()
-    return {"item": attrs.get("item", ""), "kind": attrs.get("kind", "")}
+    return {"item": attrs.get("item", ""), "kind": attrs.get("kind", ""),
+            **{k: str(v) for k, v in extra.items()}}
 
 
 def host_span(name: str, **attrs: int) -> jax.profiler.TraceAnnotation:

@@ -23,9 +23,18 @@ control coordinate is 1 and copies through otherwise (predicated iteration).
 Callers move gate bits that fall inside the ``(8, V)`` tile out of it first
 (``ops.apply_fused_gate``).
 
-Phase kernel (diagonal clusters): every marked bit above the tile is a grid
-axis, so a block needs one row of the phase table; cluster bits inside the
-tile are folded into that row, which is laid out as a whole ``(8, V)`` tile.
+Phase kernel (diagonal clusters): one streaming pass over ``f32[2, R, V]``
+in contiguous blocks of every amplitude bit below a cut ``C``, the largest
+block within ``max_block_bytes`` (1 MiB for re plus im: ``C = 17``), whatever
+the cluster (:class:`PhasePlan`).  Only the bits at or above ``C`` index the
+grid.  Cluster bits inside the ``(8, V)`` tile are folded into each phase
+tile (``phase_tile_map``).  Inside the kernel the block's row groups are
+viewed, the ``(8, V)`` tile untouched, as alternating runs of cluster and
+other bits above the tile, and the table block, whose ``2**c`` tiles are the
+phases of the ``c`` cluster bits in ``[t, C)``, is broadcast over the other
+runs.  The grid walks the cluster bits at or above ``C`` slowest, so
+consecutive steps share a table block and its copy is skipped: a call
+fetches the table once.
 
 Each ``pallas_call`` has a stable ``name`` (``fused_gate``, ``phase``) and
 ``metadata`` naming the plan item and kind it runs for
@@ -185,21 +194,6 @@ def apply_fused_gate_kernel(data: jax.Array, u_re: jax.Array,
     )(u_re, u_im, shaped)
 
 
-def _phase_kernel(p_ref, x_ref, o_ref, *, tile_rows: int):
-    """Rotate one state block by its phase tile: ``p_ref`` is the block's
-    ``(2, tile_rows, V)`` phase row (re, im), repeated over the block's
-    row groups."""
-    p = p_ref[...]
-    p_re, p_im = p[0], p[1]
-    x = x_ref[...]
-    shape = x.shape
-    rows, lane_w = shape[-2:]
-    x = x.reshape(2, rows // tile_rows, tile_rows, lane_w)
-    re, im = x[0], x[1]
-    o_ref[...] = jnp.stack([p_re * re - p_im * im,
-                            p_re * im + p_im * re]).reshape(shape)
-
-
 def phase_tile_map(qubits: Sequence[int], tile_bits: int) -> np.ndarray:
     """int32[2**tile_bits]: the cluster-index bits held by each position of
     the low ``tile_bits`` amplitude bits (cluster bit ``m`` <-> sorted
@@ -212,40 +206,138 @@ def phase_tile_map(qubits: Sequence[int], tile_bits: int) -> np.ndarray:
     return out.astype(np.int32)
 
 
+def _runs(bits: Sequence[int]) -> list[tuple[int, int]]:
+    """``(lowest, width)`` of each run of consecutive bits, ascending."""
+    runs: list[list[int]] = []
+    for b in sorted(bits):
+        if runs and runs[-1][0] + runs[-1][1] == b:
+            runs[-1][1] += 1
+        else:
+            runs.append([b, 1])
+    return [(lo, w) for lo, w in runs]
+
+
+def _deposit(x, positions: Sequence[int]):
+    """Bit ``j`` of ``x`` moved to bit ``positions[j]`` (ascending); works
+    on Python ints and on traced grid indices."""
+    out, taken = 0, 0
+    for lo, w in _runs(positions):
+        out = out + (x // (1 << taken)) % (1 << w) * (1 << lo)
+        taken += w
+    return out
+
+
+@dataclasses.dataclass(frozen=True)
+class PhasePlan:
+    """How the phase kernel walks a ``2**n`` state: one block of every
+    amplitude bit below ``cut`` per grid step.
+
+    ``inner`` are the cluster bits in ``[tile_bits, cut)``, resolved inside
+    a block; ``outer`` the cluster bits at or above ``cut``, which pick the
+    table block.  Step ``g``'s high bits are the ``outer`` coordinates and
+    its low bits the other bits at or above ``cut``, so the table block
+    changes only when an outer coordinate does.
+    """
+    n: int
+    cut: int
+    tile_bits: int
+    inner: tuple[int, ...]
+    outer: tuple[int, ...]
+
+    @property
+    def steps(self) -> int:
+        return 1 << (self.n - self.cut)
+
+    @property
+    def free(self) -> tuple[int, ...]:
+        """The non-cluster bits at or above ``cut``."""
+        return tuple(b for b in range(self.cut, self.n)
+                     if b not in self.outer)
+
+    def state_block(self, g):
+        """The block (in units of ``2**cut`` amplitudes) of step ``g``."""
+        hi, lo = divmod(g, 1 << len(self.free))
+        return (_deposit(hi, [b - self.cut for b in self.outer])
+                + _deposit(lo, [b - self.cut for b in self.free]))
+
+    def table_block(self, g):
+        """The table block (one pattern of ``outer``) of step ``g``."""
+        return g // (1 << len(self.free))
+
+    @property
+    def groups(self) -> tuple[tuple[int, bool], ...]:
+        """``(size, is_cluster)`` of the block's row-group axes above the
+        tile, most significant first: the runs of cluster and of other
+        bits in ``[tile_bits, cut)``."""
+        runs = _runs(self.inner)
+        out, prev = [], self.cut
+        for lo, w in reversed(runs):
+            if prev > lo + w:
+                out.append((1 << (prev - lo - w), False))
+            out.append((1 << w, True))
+            prev = lo
+        if prev > self.tile_bits:
+            out.append((1 << (prev - self.tile_bits), False))
+        return tuple(out)
+
+
+def phase_plan(n: int, hi_bits: Sequence[int], tile_bits: int,
+               max_block_bytes: int = 1 << 20) -> PhasePlan:
+    """The largest block of low amplitude bits whose re and im planes fit
+    ``max_block_bytes``: at least one ``(8, V)`` tile, at most the state.
+    ``hi_bits`` are the cluster bits at or above ``tile_bits``."""
+    amps = max(1, max_block_bytes // 8)
+    cut = min(n, max(tile_bits, amps.bit_length() - 1))
+    hi = tuple(sorted(hi_bits))
+    return PhasePlan(n, cut, tile_bits,
+                     tuple(b for b in hi if b < cut),
+                     tuple(b for b in hi if b >= cut))
+
+
+def _phase_kernel(p_ref, x_ref, o_ref, *, plan: PhasePlan, tile: tuple):
+    """Rotate one state block by its phases: ``p_ref`` holds the block's
+    ``2**len(plan.inner)`` phase tiles (re, im), broadcast over the row
+    groups that no cluster bit indexes."""
+    view = tuple(s for s, _ in plan.groups) + tile
+    pview = tuple(s if c else 1 for s, c in plan.groups) + tile
+    p_re = p_ref[0].reshape(pview)
+    p_im = p_ref[1].reshape(pview)
+    re = x_ref[0].reshape(view)
+    im = x_ref[1].reshape(view)
+    plane = x_ref.shape[1:]
+    # each plane is stored as it is done, so at most one is held in VMEM
+    o_ref[0] = (p_re * re - p_im * im).reshape(plane)
+    o_ref[1] = (p_re * im + p_im * re).reshape(plane)
+
+
 def apply_phase_kernel(data: jax.Array, table: jax.Array,
                        hi_bits: Sequence[int], n: int, tile_rows: int,
                        lanes: int, interpret: bool,
                        max_block_bytes: int = 1 << 20) -> jax.Array:
     """Run the phase kernel on the planar state (any shape that flattens to
-    f32[2, 2**n]); the result is in the kernel's view.
+    f32[2, 2**n]); the result is ``f32[2, R, lanes]``.
 
     ``table`` is ``f32[2, 2**len(hi_bits) * tile_rows, lanes]``: for each
     pattern of the cluster bits above the tile (``hi_bits``, sorted; bit
     ``m`` of the pattern <-> ``hi_bits[m]``) one ``(tile_rows, lanes)``
-    phase tile.
+    phase tile.  A table block is the ``2**c`` consecutive tiles of one
+    pattern of the bits at or above the cut.
     """
-    plan = make_plan(n, (), tuple(hi_bits), max_block_bytes=max_block_bytes,
-                     lanes=lanes)
-    shaped = data.reshape((2,) + plan.dims)
-    hi_axes = [i for i, r in enumerate(plan.roles) if r == "ctrl"]
-    # hi axes come MSB first: axis j holds hi bit len-1-j
-    weights = [1 << (len(hi_axes) - 1 - j) for j in range(len(hi_axes))]
-
-    def table_map(g):
-        coords = _unravel(g, plan.grid_sizes)
-        row = 0
-        for a, w in zip(hi_axes, weights):
-            row = row + coords[a] * w
-        return (0, row, 0)
-
-    spec = _state_spec(plan)
+    tile_bits = (tile_rows * lanes).bit_length() - 1
+    plan = phase_plan(n, hi_bits, tile_bits, max_block_bytes)
+    rows, block_rows = (1 << n) // lanes, (1 << plan.cut) // lanes
+    state = pl.BlockSpec((2, block_rows, lanes),
+                         lambda g: (0, plan.state_block(g), 0))
+    phases = pl.BlockSpec((2, tile_rows << len(plan.inner), lanes),
+                          lambda g: (0, plan.table_block(g), 0))
     return pl.pallas_call(
-        functools.partial(_phase_kernel, tile_rows=tile_rows),
-        grid=(plan.grid,),
-        in_specs=[pl.BlockSpec((2, tile_rows, lanes), table_map), spec],
-        out_specs=spec,
-        out_shape=jax.ShapeDtypeStruct(shaped.shape, jnp.float32),
+        functools.partial(_phase_kernel, plan=plan, tile=(tile_rows, lanes)),
+        grid=(plan.steps,),
+        in_specs=[phases, state],
+        out_specs=state,
+        out_shape=jax.ShapeDtypeStruct((2, rows, lanes), jnp.float32),
         interpret=interpret,
         name="phase",
-        metadata=scopes.kernel_metadata(),
-    )(table, shaped)
+        metadata=scopes.kernel_metadata(steps=plan.steps,
+                                        block_bytes=8 << plan.cut),
+    )(table, data.reshape(2, rows, lanes))

@@ -131,9 +131,11 @@ def apply_phase_gate(data: jax.Array, n: int, v: int,
 
     A permutation cluster is applied as its monomial matrix through the
     dense kernel (no gather inside a kernel).  A diagonal one streams the
-    state once through the phase kernel: cluster bits above the vector tile
-    select a row of the phase table, and the bits inside it are spread
-    over that row as a whole ``(8, V)`` tile.
+    state once through the phase kernel in ~``max_block_bytes`` blocks:
+    cluster bits above the vector tile select a phase tile of the table
+    (those inside a block within it, those above from the grid), and the
+    bits inside the tile are spread over each tile as a whole ``(8, V)``
+    tile.
     """
     return phase_gate(data, n, v, qubits, p_re, p_im, perm, interpret,
                       max_block_bytes).reshape(data.shape)
@@ -166,6 +168,8 @@ def phase_gate(data: jax.Array, n: int, v: int, qubits: tuple[int, ...],
     tmap = phase_tile_map(qubits, t)
     n_lo = len(qubits) - len(hi)
 
+    # one (tile_rows, V) tile per pattern of ``hi``, in pattern order: the
+    # tiles of one pattern of the bits above the kernel's cut are adjacent
     def table(p):
         return p.reshape(-1, 1 << n_lo)[:, tmap].reshape(-1, 1 << v)
 

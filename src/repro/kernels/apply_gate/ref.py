@@ -29,9 +29,12 @@ def apply_fused_gate_ref(data: jax.Array, n: int, v: int,
 def apply_phase_gate_ref(data: jax.Array, n: int, v: int,
                          qubits: tuple[int, ...], p_re, p_im,
                          perm=None) -> jax.Array:
-    """Oracle for the diag/perm kernel: materialize the monomial unitary
-    densely and route it through ``apply_gate_dense`` — a deliberately
-    different code path (no index maps, no phase broadcast)."""
+    """Oracle for the diag/perm kernel, on the flat complex vector — a
+    deliberately different code path (no tiles, no phase tables).  A
+    diagonal multiplies amplitude ``x`` by the phase its cluster bits
+    select, so clusters of any width stay cheap; a permutation
+    materializes the monomial unitary densely and routes it through
+    ``apply_gate_dense``."""
     import numpy as np
     w = len(qubits)
     dim = 1 << w
@@ -39,7 +42,14 @@ def apply_phase_gate_ref(data: jax.Array, n: int, v: int,
         phase = np.ones(dim, np.complex64)
     else:
         phase = (np.asarray(p_re) + 1j * np.asarray(p_im)).astype(np.complex64)
-    src = np.arange(dim) if perm is None else np.asarray(perm)
+    if perm is None:
+        x = np.arange(1 << n)
+        sel = sum(((x >> q) & 1) << m for m, q in enumerate(qubits))
+        flat = np.asarray(data, np.float32).reshape(2, 1 << n)
+        psi = (flat[0] + 1j * flat[1]).astype(np.complex64) * phase[sel]
+        out = np.stack([psi.real, psi.imag]).astype(np.float32)
+        return jnp.asarray(out.reshape(data.shape))
+    src = np.asarray(perm)
     u = np.zeros((dim, dim), np.complex64)
     u[np.arange(dim), src] = phase
     return apply_fused_gate_ref(data, n, v, tuple(qubits),

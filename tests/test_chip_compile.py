@@ -48,14 +48,14 @@ def one_chip():
 
 def _compile(one_chip, fn, *args, donate=True):
     """Compile ``fn`` (state first, donated when ``fn`` returns a state) for
-    the chip; returns the temporaries in bytes."""
+    the chip; returns the compiled program."""
     specs = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
              for a in args]
     compiled = jax.jit(fn, donate_argnums=(0,) if donate else ()).lower(
         *specs).compile()
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp <= 4 * STATE_BYTES, f"temporaries {temp} > 4x state"
-    return temp
+    return compiled
 
 
 def _unitary(k):
@@ -83,14 +83,27 @@ def test_pallas_fused_gate(one_chip, qubits, controls):
 
 @pytest.mark.parametrize("qubits,perm", [
     ((2, 9, 13, 21), None), ((0, 1, 2, 3, 4, 5, 6, 7, 8, 9, 10, 11), None),
-    ((3, 12, 20), np.array([1, 0, 3, 2, 5, 4, 7, 6]))])
+    ((3, 12, 20), np.array([1, 0, 3, 2, 5, 4, 7, 6])),
+    # lowest bit above the (8, 128) tile at its top, 10, and cluster bits
+    # on both sides of the block's cut (17)
+    ((3, 10, 11, 15, 21), None),
+    # the qrc28 grid circuit's first width-21 diagonal, bits below 24
+    ((0, 1, 2, 4, 5, 6, 7, 9, 10, 11, 13, 14, 15, 16, 17, 19, 20, 22),
+     None)])
 def test_pallas_phase_gate(one_chip, qubits, perm):
     w = 1 << len(qubits)
-    _compile(one_chip,
-             lambda d, pr, pi: K.apply_phase_gate(d, N, V, qubits, pr, pi,
-                                                  perm=perm, interpret=False),
-             STATE, jax.ShapeDtypeStruct((w,), jnp.float32),
-             jax.ShapeDtypeStruct((w,), jnp.float32))
+    compiled = _compile(
+        one_chip,
+        lambda d, pr, pi: K.apply_phase_gate(d, N, V, qubits, pr, pi,
+                                             perm=perm, interpret=False),
+        STATE, jax.ShapeDtypeStruct((w,), jnp.float32),
+        jax.ShapeDtypeStruct((w,), jnp.float32))
+    if perm is None:
+        # a diagonal streams 1 MiB blocks (2**17 amplitudes), whatever
+        # its bits: 2**(24 - 17) grid steps
+        meta = compiled.as_text().replace("\n", "")
+        assert '"block_bytes":"1048576"' in meta
+        assert '"steps":"128"' in meta
 
 
 @pytest.mark.parametrize("qubit", [3, 9, 20])
