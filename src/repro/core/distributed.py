@@ -30,6 +30,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import apply as A
 from repro.core import fusion as F
+from repro.core import scopes
 from repro.core.circuits import Circuit
 from repro.core.gates import Gate
 from repro.core.target import Target, row_budget
@@ -66,16 +67,20 @@ def swap_block(data: jax.Array, axis: str, n_local: int, local_lo: int,
     ``data``'s trailing dimensions must flatten to ``2**n_local`` local
     amplitudes (the planar ``(R_local, V)`` tile or any reshape of it);
     arbitrary leading axes (planes, batch) are preserved, so the same
-    primitive serves the single-state and the batched sharded paths.
+    primitive serves the single-state and the batched sharded paths.  Its
+    operations carry ``repro_part="collective"``
+    (:mod:`repro.core.scopes`).
     """
     shape = data.shape
     pre = 1 << (n_local - local_lo - a_bits)
     mid = 1 << a_bits
     post = 1 << local_lo
     lanes = min(post, shape[-1])          # keep the lane axis whole
-    x = data.reshape(-1, pre, mid, post // lanes, lanes)
-    x = jax.lax.all_to_all(x, axis, split_axis=2, concat_axis=2, tiled=True)
-    return x.reshape(shape)
+    with scopes.device_scope(part="collective"):
+        x = data.reshape(-1, pre, mid, post // lanes, lanes)
+        x = jax.lax.all_to_all(x, axis, split_axis=2, concat_axis=2,
+                               tiled=True)
+        return x.reshape(shape)
 
 
 def pick_victim(needed: Sequence[int], a_bits: int, top: int,

@@ -116,7 +116,8 @@ class Simulator:
         the plan cache (``repro.engine.plan``); repeat runs of the same
         structure are single dispatches of the compiled program.  With
         ``mesh=`` set the program executes state-sharded over the devices
-        (``CompiledPlan.run_sharded_batch_raw`` with a batch of one).
+        (``CompiledPlan.run_sharded``), and the state it returns keeps its
+        rows sharded over them.
 
         The call is the profiler span ``repro.sim.run``, the plan lookup
         ``repro.plan.lookup`` inside it (:mod:`repro.core.scopes`).
@@ -134,8 +135,9 @@ class Simulator:
             pm = (np.zeros((1, plan.num_params), np.float32)
                   if params is None
                   else np.asarray(params, np.float32).reshape(1, -1))
-            raw = plan.run_sharded_batch_raw(pm, self._mesh_for(spec))
-            return plan._wrap(raw[0])
+            with scopes.host_span("repro.dispatch"):
+                data = plan.run_sharded(pm, self._mesh_for(spec))
+            return plan._wrap(data)
 
     # -- observables -----------------------------------------------------------
     def expectation_z(self, state: SV.State, qubit: int) -> jax.Array:

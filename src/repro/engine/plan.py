@@ -663,14 +663,16 @@ def _local_perm_map(rho: tuple[int, ...]) -> np.ndarray:
 
 def _apply_local_bit_perm(data: jax.Array, rho: Sequence[int]) -> jax.Array:
     """Apply a local bit-position permutation as one static gather over the
-    flattened trailing (row, lane) axes; leading axes are preserved."""
+    flattened trailing (row, lane) axes; leading axes are preserved.  Its
+    operations carry ``repro_part="exchange"``."""
     rho = tuple(rho)
     if rho == tuple(range(len(rho))):
         return data
     m = _local_perm_map(rho)
     shape = data.shape
-    flat = data.reshape(shape[:-2] + (-1,))
-    return flat[..., m].reshape(shape)
+    with scopes.device_scope(part="exchange"):
+        flat = data.reshape(shape[:-2] + (-1,))
+        return flat[..., m].reshape(shape)
 
 
 def _compact_rho(needed: Sequence[int], n_local: int) -> tuple[int, ...]:
@@ -753,6 +755,26 @@ def _sharded_dense_step(item: PlanItem, phys: tuple[int, ...],
             pred = cond if pred is None else jnp.logical_and(pred, cond)
         return jax.lax.cond(pred, apply, lambda d: d, data)
     return step
+
+
+def sharded_windows_kept(items: Sequence[PlanItem], perm: Sequence[int],
+                         first: int, n_local: int, v: int) -> bool:
+    """The sharded program's victim rule: whether, under the logical to
+    physical bit map ``perm``, each item from ``first`` up to the next one
+    that needs a qubit-block swap can exchange its lane bits with a free
+    block of row bits (:func:`repro.core.apply.lane_window`).  One that
+    cannot takes a view whose minor axis is narrower than the ``2**v``
+    lanes, which a tiled target pads to whole tiles (32x at n = 30 on a
+    v5e, more memory than the chip has)."""
+    for item in items[first:]:
+        if item.kind == "diag":
+            continue
+        bits = [perm[q] for q in item.qubits + item.controls]
+        if max(perm[q] for q in item.qubits) >= n_local:
+            return True
+        if min(bits) < v and A.lane_window(n_local, v, bits) is None:
+            return False
+    return True
 
 
 def _restore_identity(data: jax.Array, perm: list[int], n: int,
@@ -1364,10 +1386,41 @@ class CompiledPlan:
         returned global array is an ordinary stacked planar state (the
         trailing permutation is restored inside the traced program).
         """
+        pm = self._sharded_params(params_matrix)
+        bs = self._sharded_mesh_check(mesh)
+        b = pm.shape[0]
+        padded = -(-b // bs) * bs
+        if padded > b:
+            pm = np.concatenate([pm, np.repeat(pm[-1:], padded - b, axis=0)])
+        raw = self._run_sharded(("sharded", padded, mesh),
+                                lambda: self._build_sharded(mesh, padded), pm,
+                                mesh)
+        return raw[:b]
+
+    def run_sharded(self, params, mesh) -> jax.Array:
+        """Run one binding state-sharded over ``mesh``, whose batch axis
+        must have one device; returns the state's planar data
+        ``[2, R, V]``, its rows sharded over the state axis.  The program
+        itself drops the batch axis, so no copy of the whole state is made
+        to drop it afterwards (``run_sharded_batch_raw(...)[0]`` makes
+        one)."""
+        pm = self._sharded_params(params)
+        if pm.shape[0] != 1 or self._sharded_mesh_check(mesh) != 1:
+            raise ValueError("run_sharded takes one binding on a mesh whose "
+                             f"{D.BATCH_AXIS!r} axis has one device")
+        return self._run_sharded(
+            ("sharded_one", mesh),
+            lambda: self._build_sharded(mesh, 1, single=True), pm, mesh)
+
+    def _sharded_params(self, params_matrix) -> np.ndarray:
         pm = np.atleast_2d(np.asarray(params_matrix, np.float32))
         if pm.ndim != 2 or pm.shape[1] != self.num_params:
             raise ValueError(f"{self.template.name}: params matrix must be "
                              f"[B, {self.num_params}], got {tuple(pm.shape)}")
+        return pm
+
+    def _sharded_mesh_check(self, mesh) -> int:
+        """Check ``mesh`` against this plan; returns its batch shards."""
         if self.backend != "planar":
             raise ValueError(
                 f"sharded execution lowers items with the planar "
@@ -1381,19 +1434,18 @@ class CompiledPlan:
                 f"(needs {D.BATCH_AXIS!r} and {D.STATE_AXIS!r} with "
                 f"{1 << self.state_bits} state shards; recompile with the "
                 f"right state_bits for a different mesh)")
-        bs = axis_sizes[D.BATCH_AXIS]
-        b = pm.shape[0]
-        padded = -(-b // bs) * bs
-        if padded > b:
-            pm = np.concatenate([pm, np.repeat(pm[-1:], padded - b, axis=0)])
-        key = ("sharded", padded, mesh)
+        return axis_sizes[D.BATCH_AXIS]
+
+    def _run_sharded(self, key, build: Callable, pm: np.ndarray,
+                     mesh) -> jax.Array:
+        from jax.sharding import NamedSharding, PartitionSpec as P
         with self._plock:
-            entry = self._get_or_build(
-                key, lambda: self._build_sharded(mesh, padded))
-        fn, counter = entry
-        raw = fn(jnp.asarray(pm))
+            fn, counter = self._get_or_build(key, build)
+        # the parameters placed as the program takes them, so that its
+        # lowering does not depend on where an uncommitted array lies
+        raw = fn(jax.device_put(pm, NamedSharding(mesh, P(D.BATCH_AXIS))))
         self.sharded_swaps = counter["swaps"]
-        return raw[:b]
+        return raw
 
     def _sharded_item_step(self, item: PlanItem, phys: tuple[int, ...],
                            cphys: tuple[int, ...], n_local: int):
@@ -1414,12 +1466,21 @@ class CompiledPlan:
         glob_ctrl = tuple(p for p in cphys if p >= n_local)
         return _sharded_dense_step(item, phys, local_ctrl, glob_ctrl, n_local)
 
-    def _build_sharded(self, mesh, padded_b: int):
+    def _build_sharded(self, mesh, padded_b: int, single: bool = False):
         """Trace the sharded program: one ``shard_map`` whose body loops the
         plan items with trace-time permutation tracking, Belady victim
         selection, and a final permutation restore; the batch dimension is
         vmapped *inside* each item step while collectives act on the whole
-        local batch block."""
+        local batch block.  ``single`` (a batch of one) applies the steps to
+        the state itself, with no batch axis to vmap or to return.
+
+        Each item's operations carry its ``repro_item``, ``repro_kind`` and
+        ``repro_width``, as in the single-device program; the qubit-block
+        swaps (:func:`repro.core.distributed.swap_block`) carry
+        ``repro_part="collective"``, the local bit gathers
+        ``repro_part="exchange"``, the zero-state build
+        ``repro_kind="init"`` and the restore at the end
+        ``repro_kind="restore"``."""
         n, v, s = self.n, self.target.lane_qubits, self.state_bits
         n_local = n - s
         # swap victims above the (8, V) vector tile where there is room
@@ -1444,20 +1505,27 @@ class CompiledPlan:
         counter = {"swaps": 0}
 
         def local_fn(pm_local):
-            # pm_local: f32[bl, P]; local state block f32[bl, 2, R_local, V]
-            data = jnp.zeros((bl, 2, 1 << (n_local - v), 1 << v), jnp.float32)
-            if s:
-                amp0 = jnp.where(jax.lax.axis_index(D.STATE_AXIS) == 0,
-                                 1.0, 0.0)
-            else:
-                amp0 = 1.0
-            data = data.at[:, 0, 0, 0].set(amp0)
+            # pm_local: f32[bl, P]; local state block f32[bl, 2, R_local, V],
+            # or f32[2, R_local, V] for a single state (no vmap)
+            lead = () if single else (bl,)
+            with scopes.device_scope(kind="init"):
+                data = jnp.zeros(lead + (2, 1 << (n_local - v), 1 << v),
+                                 jnp.float32)
+                if s:
+                    amp0 = jnp.where(jax.lax.axis_index(D.STATE_AXIS) == 0,
+                                     1.0, 0.0)
+                else:
+                    amp0 = 1.0
+                data = data.at[..., 0, 0, 0].set(amp0)
             perm = list(range(n))
             swaps = 0
             # victim blocks of the exchanges so far; None once a local
             # gather has moved bits (then the general restore runs)
             swapped = []
             for ii, item in enumerate(items):
+                tag = functools.partial(scopes.device_scope, item=ii,
+                                        kind=item.kind,
+                                        width=len(item.qubits), part="apply")
                 phys = [perm[q] for q in item.qubits]
                 cphys = [perm[q] for q in item.controls]
                 # diagonal items never need locality (zero-communication
@@ -1471,8 +1539,13 @@ class CompiledPlan:
                             inv[p] = q
 
                         def score(blk):
-                            return min(next_use(inv[p], ii)
-                                       for p in range(blk, blk + s))
+                            # first a block that leaves every item up to the
+                            # next swap a lane window, then Belady
+                            after = D.swap_perm(perm, n_local, blk, s)
+                            return (sharded_windows_kept(items, after, ii,
+                                                         n_local, v),
+                                    min(next_use(inv[p], ii)
+                                        for p in range(blk, blk + s)))
                         return D.pick_victim(needed, s, n_local, score=score,
                                              floor=tile_floor)
 
@@ -1488,13 +1561,16 @@ class CompiledPlan:
                         # scattered positions blocked every window: pack
                         # them into the low bits with one static gather
                         rho = _compact_rho(needed, n_local)
-                        data = _apply_local_bit_perm(data, rho)
+                        with tag():
+                            data = _apply_local_bit_perm(data, rho)
                         perm = [rho[p] if p < n_local else p for p in perm]
                         needed = [rho[p] if p < n_local else p
                                   for p in needed]
                         tgt = pick(needed)
                         swapped = None
-                    data = D.swap_block(data, D.STATE_AXIS, n_local, tgt, s)
+                    with tag():
+                        data = D.swap_block(data, D.STATE_AXIS, n_local, tgt,
+                                            s)
                     if swapped is not None:
                         swapped.append(tgt)
                     perm = D.swap_perm(perm, n_local, tgt, s)
@@ -1503,26 +1579,33 @@ class CompiledPlan:
                     cphys = [perm[q] for q in item.controls]
                 step = self._sharded_item_step(item, tuple(phys),
                                                tuple(cphys), n_local)
-                data = jax.vmap(step)(data, pm_local)
-            if swapped is not None:
-                # only block exchanges moved bits: undo them in reverse, with
-                # no local gather (a 2**n_local index map is a constant the
-                # TPU compiler handles very slowly)
-                for tgt in reversed(swapped):
-                    data = D.swap_block(data, D.STATE_AXIS, n_local, tgt, s)
-                restore_swaps = len(swapped)
-            else:
-                data, restore_swaps = _restore_identity(data, perm, n,
-                                                        n_local)
+                with tag():
+                    data = (step(data, pm_local[0]) if single
+                            else jax.vmap(step)(data, pm_local))
+            with scopes.device_scope(kind="restore"):
+                if swapped is not None:
+                    # only block exchanges moved bits: undo them in reverse,
+                    # with no local gather (a 2**n_local index map is a
+                    # constant the TPU compiler handles very slowly)
+                    for tgt in reversed(swapped):
+                        data = D.swap_block(data, D.STATE_AXIS, n_local, tgt,
+                                            s)
+                    restore_swaps = len(swapped)
+                else:
+                    data, restore_swaps = _restore_identity(data, perm, n,
+                                                            n_local)
             counter["swaps"] = swaps + restore_swaps
             return data
 
         from jax.sharding import PartitionSpec as P
 
+        # a single state's rows are split over both axes, the batch axis
+        # having one device: the same layout as the state axis alone
+        out_specs = (P(None, (D.BATCH_AXIS, D.STATE_AXIS), None) if single
+                     else P(D.BATCH_AXIS, None, D.STATE_AXIS, None))
         fn = jax.shard_map(local_fn, mesh=mesh,
                            in_specs=(P(D.BATCH_AXIS, None),),
-                           out_specs=P(D.BATCH_AXIS, None, D.STATE_AXIS,
-                                       None))
+                           out_specs=out_specs)
         return jax.jit(fn), counter
 
 

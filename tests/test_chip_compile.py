@@ -27,10 +27,9 @@ STATE_BYTES = 2 * 4 << N
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache as cc
-    from jax.sharding import SingleDeviceSharding
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     try:
         topo = topologies.get_topology_desc(platform="tpu",
@@ -42,8 +41,14 @@ def one_chip():
     enabled = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     cc.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", enabled)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+    return SingleDeviceSharding(topo.devices[0])
 
 
 def _compile(one_chip, fn, *args, donate=True):
@@ -148,3 +153,43 @@ def test_planar_x_layer_plan(one_chip):
     assert any(it.kind == "perm" for it in plan.items)
     params = jax.ShapeDtypeStruct((plan.num_params,), jnp.float32)
     _compile(one_chip, plan._program(), STATE, params)
+
+
+def _grid_rcs(n: int):
+    """The ``qrc30`` cell's 2-D grid random circuit cut to a 4 x 6 grid."""
+    import importlib.util
+    import pathlib
+    path = (pathlib.Path(__file__).resolve().parents[1] / "bench"
+            / "families" / "grid_rcs.py")
+    spec = importlib.util.spec_from_file_location("grid_rcs", path)
+    family = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(family)
+    return family.program_template({"name": "grid_rcs", "rows": 4,
+                                    "cols": n // 4, "n": n, "depth": 14})
+
+
+@pytest.mark.parametrize("circuit", ["qrc", "grid_rcs"])
+def test_sharded_qrc_plan(topo, circuit):
+    """The whole-circuit program state-sharded over the four chips of the
+    described host (``CompiledPlan._build_sharded``, a batch of one), 22
+    local qubits a chip, for the 1-D brickwork and for the ``qrc30`` cell's
+    2-D grid circuit: each plan item's lane bits keep a block of row bits
+    to be exchanged with, so no view pads the lanes, and each chip's
+    temporaries stay within 4x its local state."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.core import distributed as D
+    mesh = Mesh(np.array(topo.devices[:4]).reshape(1, 4),
+                (D.BATCH_AXIS, D.STATE_AXIS))
+    template = (template_of(C.qrc(N, depth=8)) if circuit == "qrc"
+                else _grid_rcs(N))
+    plan = compile_plan(template, backend="planar", target=TPU_V5E,
+                        state_bits=2)
+    fn, counter = plan._build_sharded(mesh, 1, single=True)
+    params = jax.ShapeDtypeStruct((1, plan.num_params), jnp.float32,
+                                  sharding=NamedSharding(mesh,
+                                                         P(D.BATCH_AXIS)))
+    compiled = fn.lower(params).compile()
+    assert counter["swaps"] >= 2
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp <= 4 * STATE_BYTES // 4, f"temporaries {temp} > 4x local"

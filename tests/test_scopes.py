@@ -2,13 +2,20 @@
 
 The compiled programs carry ``repro_*`` frontend attributes on every
 operation the program emits (plan item, kind, width, exchange or apply,
-epilogue term); the attributes change nothing else in the compiled
-program; and ``Simulator.run`` and the serving path put ``repro.*`` host
-spans, nested by stage, into a profile.
+collective, epilogue term); the attributes change nothing else in the
+compiled program; and ``Simulator.run`` and the serving path put
+``repro.*`` host spans, nested by stage, into a profile.  The
+state-sharded program needs four devices, so it is compiled in a
+subprocess with four host devices.
 """
 import contextlib
 import glob
+import json
+import os
 import re
+import subprocess
+import sys
+import textwrap
 import time
 
 import jax
@@ -79,12 +86,67 @@ def _xla_made(name: str, root_opcode: str | None) -> bool:
     return name.startswith("wrapped_") or root_opcode in ("bitcast", "copy")
 
 
+SHARDED = textwrap.dedent("""
+    import json, re, sys
+    sys.path.insert(0, {src!r})
+    import jax, jax.numpy as jnp, numpy as np
+    from jax.sharding import Mesh
+    from repro.core import distributed as D
+    from repro.core.target import CPU_TEST
+    from repro.engine import hea_template
+    from repro.engine.plan import compile_plan
+    t = hea_template({n}, 2)
+    plan = compile_plan(t, backend="planar", target=CPU_TEST, state_bits=2)
+    mesh = Mesh(np.array(jax.devices()).reshape(1, 4),
+                (D.BATCH_AXIS, D.STATE_AXIS))
+    fn, counter = plan._build_sharded(mesh, 1, single=True)
+    lowered = fn.lower(jax.ShapeDtypeStruct((1, t.num_params), jnp.float32))
+    text = lowered.compile().as_text()
+    print(json.dumps({{"swaps": counter["swaps"], "optimized": text,
+                      "hlo": lowered.as_text("hlo")}}))
+""").format(src=os.path.join(os.path.dirname(__file__), "..", "src"), n=N)
+
+
+def _sharded():
+    """The whole-circuit program state-sharded over four host devices
+    (``CompiledPlan._build_sharded``, a batch of one): its optimized and
+    its lowered HLO text, and the swaps it traced."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    out = subprocess.run([sys.executable, "-c", SHARDED], env=env,
+                         capture_output=True, text=True, timeout=300)
+    assert out.returncode == 0, out.stderr[-4000:]
+    return json.loads(out.stdout.strip().splitlines()[-1])
+
+
+def _cpu_collective_rewrite(line: str, root_opcode: str | None) -> bool:
+    """On the CPU, XLA splits each ``all_to_all`` of the sharded program
+    into slices and a concatenate, merges them with the concatenates
+    around, and folds constant indices into operand-less fusions.  The
+    slices and concatenates it builds keep the scope's name in their
+    metadata (``item=...``, ``kind=...``) but not its attributes.  The TPU
+    keeps the ``all_to_all`` whole."""
+    if re.search(r"= \S+ fusion\(\)", line):
+        return True
+    name = re.search(r'op_name="([^"]*)"', line)
+    return (root_opcode in ("slice", "concatenate") and name is not None
+            and re.search(r"(item|kind)=", name.group(1)) is not None)
+
+
 @pytest.fixture(scope="module")
-def compiled():
-    return {name: _optimized(fn, args) for name, fn, args in _programs()}
+def sharded():
+    return _sharded()
 
 
-@pytest.mark.parametrize("program", ["planar", "pallas", "expectation"])
+@pytest.fixture(scope="module")
+def compiled(sharded):
+    out = {name: _optimized(fn, args) for name, fn, args in _programs()}
+    out["sharded"] = sharded["optimized"]
+    return out
+
+
+@pytest.mark.parametrize("program",
+                         ["planar", "pallas", "expectation", "sharded"])
 def test_every_emitted_op_is_attributed(compiled, program):
     text = compiled[program]
     instrs = list(_instructions(text))
@@ -99,6 +161,8 @@ def test_every_emitted_op_is_attributed(compiled, program):
         if _xla_made(name, root):
             continue
         checked += 1
+        if program == "sharded" and _cpu_collective_rewrite(line, root):
+            continue
         if "repro_item=" not in line and "repro_kind=" not in line:
             untagged += 1
             print("untagged:", line[:200])
@@ -106,9 +170,27 @@ def test_every_emitted_op_is_attributed(compiled, program):
     assert untagged == 0
 
 
-@pytest.mark.parametrize("program", ["planar", "pallas", "expectation"])
+@pytest.mark.parametrize("program",
+                         ["planar", "pallas", "expectation", "sharded"])
 def test_exchanges_are_tagged(compiled, program):
     assert 'repro_part="exchange"' in compiled[program]
+
+
+def test_sharded_swaps_are_tagged_collectives(sharded):
+    """Each qubit-block swap is one ``all_to_all`` tagged
+    ``repro_part="collective"``, the restores at the end (``repro_kind=
+    "restore"``) included, and their count is the program's swap
+    counter; the plan items keep their item tags."""
+    a2a = [ln for ln in sharded["hlo"].splitlines()
+           if re.search(r"= \S+ all-to-all\(", ln)]
+    assert sharded["swaps"] >= 3
+    assert len(a2a) == sharded["swaps"]
+    assert all('repro_part="collective"' in ln for ln in a2a)
+    assert any('repro_kind="restore"' in ln for ln in a2a)
+    assert any("repro_item=" in ln for ln in a2a)
+    items = set(re.findall(r'repro_item="(\d+)"', sharded["optimized"]))
+    assert len(items) > 5
+    assert 'repro_kind="init"' in sharded["optimized"]
 
 
 def test_items_and_epilogue_terms_are_tagged(compiled):
