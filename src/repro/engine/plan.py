@@ -32,7 +32,9 @@ Every operation a program emits carries device attributes
 ``repro_item`` (its index in ``CompiledPlan.items``), ``repro_kind``,
 ``repro_width`` and ``repro_part`` (``apply``, or ``exchange`` inside
 ``core.apply.exchange``); the result epilogue's ``repro_kind="epilogue"``
-and ``repro_term``; the zero-state build ``repro_kind="init"``.  ``run``
+and ``repro_term`` (``z`` on the pass shared by every Z-string, the
+observable's index on each other string's reduction); the zero-state build
+``repro_kind="init"``.  ``run``
 puts its host stages in the profiler trace as ``repro.*`` spans.
 """
 from __future__ import annotations
@@ -844,6 +846,14 @@ def _restore_identity(data: jax.Array, perm: list[int], n: int,
     return data, swaps
 
 
+def _z_string_mask(obs: tuple) -> int | None:
+    """The qubit mask of a canonical Pauli string whose every letter is
+    ``Z`` (a diagonal observable), or None for a string with an X or Y."""
+    if all(p == "Z" for _, p in obs):
+        return sum(1 << q for q, _ in obs)
+    return None
+
+
 def _tagged(step: Callable, index: int, item: PlanItem) -> Callable:
     """``step`` with the operations it traces tagged as plan item
     ``index`` (its position in ``CompiledPlan.items``); given ``shape``,
@@ -1242,25 +1252,12 @@ class CompiledPlan:
         return step
 
     def _observable_step(self, obs: tuple):
-        """Reduction ``(state) -> f32`` for one canonical Pauli string, on
-        the state in any shape that flattens to the plan's layout.
-
-        pallas routes the single-qubit-Z case through the streaming
-        expectation kernel (the paper's §IV reduction); everything else
-        takes the planar/dense apply-then-inner-product fallback.
+        """Apply-then-inner-product reduction ``(state) -> f32`` for one
+        canonical Pauli string, on the state in any shape that flattens to
+        the plan's layout.  The epilogue takes it for strings with an X or
+        a Y; Z-strings share :meth:`_z_pass`.
         """
         n = self.n
-        if (self.backend == "pallas" and len(obs) == 1 and obs[0][1] == "Z"):
-            from repro.kernels.expectation import ops as EXP
-            qubit = obs[0][0]
-            v = self.target.lane_qubits
-            interpret = self.interpret
-
-            def step(data):
-                return EXP.expectation_z(
-                    data.reshape(2, 1 << (n - v), 1 << v), n, v, qubit,
-                    interpret=interpret)
-            return step
         if self.backend == "dense":
             us = [(q, jnp.asarray(np.asarray(ME._PAULI[p], np.complex64)))
                   for q, p in obs]
@@ -1286,6 +1283,44 @@ class CompiledPlan:
             return jnp.sum(a[0] * b[0] + a[1] * b[1])
         return step
 
+    def _z_pass(self, masks: Sequence[int]):
+        """One reduction ``(state) -> f32[T]`` for the T Z-strings with
+        qubit masks ``masks``: <Z_m> = sum_x |amp_x|^2 (-1)**popcount(x & m).
+
+        With amplitude ``x`` at row ``x >> v``, lane ``x & (V - 1)`` the
+        sign factors into a lane sign times a row sign, so the pass forms
+        |amp|^2 as an ``(R, V)`` tile (no flat view), multiplies it by the
+        ``(V, T)`` lane signs at HIGHEST and sums each column against its
+        row signs: one read of the state for every term.  Both sign
+        matrices are built in the program from ``iota`` and the masks.
+        """
+        n = self.n
+        v = min(self.target.lane_qubits, n)
+        shape = (1 << (n - v), 1 << v)
+        lane_masks = np.array([m & ((1 << v) - 1) for m in masks], np.int32)
+        row_masks = np.array([m >> v for m in masks], np.int32)
+
+        def signs(size, m):
+            bits = jax.lax.population_count(
+                jax.lax.iota(jnp.int32, size)[:, None] & m[None, :]) & 1
+            return (1 - 2 * bits).astype(jnp.float32)
+
+        if self.backend == "dense":
+            def probs(psi):
+                re, im = jnp.real(psi), jnp.imag(psi)
+                return (re * re + im * im).reshape(shape)
+        else:
+            def probs(data):
+                sq = data.reshape((2,) + shape)
+                sq = sq * sq
+                return sq[0] + sq[1]
+
+        def step(data):
+            q = jnp.dot(probs(data), signs(shape[1], lane_masks),
+                        precision=A.HIGHEST)
+            return jnp.sum(q * signs(shape[0], row_masks), axis=0)
+        return step
+
     def _epilogue_step(self, spec):
         """Fused result epilogue ``(state, key) -> payload``."""
         from repro.engine import results as R
@@ -1298,17 +1333,48 @@ class CompiledPlan:
                                            jax.random.fold_in(key,
                                                               _SHOT_SALT))
             return epi
-        # expectation / noisy: one reduction per observable, stacked
-        steps = [self._observable_step(obs) for obs in spec.observables]
+        # expectation / noisy: the Z-strings in one shared pass, every other
+        # string by its own reduction; the payload keeps the spec's order
+        masks = [_z_string_mask(obs) for obs in spec.observables]
+        z_terms = [i for i, m in enumerate(masks) if m is not None]
+        rest = [(i, self._observable_step(obs))
+                for i, obs in enumerate(spec.observables)
+                if masks[i] is None]
+        z_pass = (self._z_pass([masks[i] for i in z_terms]) if z_terms
+                  else None)
+        order = np.argsort(z_terms + [i for i, _ in rest])
 
         def epi(data, key):
             with scopes.device_scope(kind="epilogue"):
-                terms = []
-                for i, s in enumerate(steps):
+                parts = []
+                if z_pass is not None:
+                    with scopes.device_scope(term="z"):
+                        parts.append(z_pass(data))
+                for i, s in rest:
                     with scopes.device_scope(term=i):
-                        terms.append(s(data))
-                return jnp.stack(terms).astype(jnp.float32)
+                        parts.append(s(data)[None])
+                out = jnp.concatenate(parts).astype(jnp.float32)
+                if rest and z_terms:
+                    out = out[order]
+                return out
         return epi
+
+    @property
+    def z_pass_terms(self) -> int:
+        """Observables of the result spec that the shared Z-string pass
+        serves (0 without an expectation or noisy spec)."""
+        if self.result is None:
+            return 0
+        return sum(_z_string_mask(o) is not None
+                   for o in self.result.observables)
+
+    @property
+    def fallback_terms(self) -> int:
+        """Observables of the result spec served one by one by the
+        apply-then-inner-product reduction (strings with an X or a Y)."""
+        if self.result is None:
+            return 0
+        return len(self.result.observables) - self.z_pass_terms
 
     def _result_program(self):
         """The full result-mode program ``(state, params, rowkey) -> payload``.

@@ -540,11 +540,15 @@ class ServedActivity:
     amplitudes it served, so ``summary()`` answers the serving-side
     Table-IV question: over everything this engine executed, what lane
     occupancy ran and what fraction of amplitude traffic took the
-    diagonal/permutation fast path.
+    diagonal/permutation fast path.  For result plans it also counts the
+    observables each row asked for, split by route: ``z_pass_term_frac``
+    is the share that the epilogue's shared Z-string pass served, the rest
+    took the per-term fallback (0.0 when no observable was served).
     """
 
     _ZERO = {"rows": 0, "batches": 0, "amps": 0.0, "alo_w": 0.0,
-             "orr_w": 0.0, "ai_w": 0.0, "fast_w": 0.0, "saved_w": 0.0}
+             "orr_w": 0.0, "ai_w": 0.0, "fast_w": 0.0, "saved_w": 0.0,
+             "z_terms": 0, "fallback_terms": 0}
 
     def __init__(self):
         self._lock = threading.Lock()
@@ -572,6 +576,8 @@ class ServedActivity:
             e["rows"] += int(rows)
             e["batches"] += 1
             e["amps"] += amps
+            e["z_terms"] += plan.z_pass_terms * int(rows)
+            e["fallback_terms"] += plan.fallback_terms * int(rows)
             if prof is not None:
                 e["alo_w"] += prof.alo * amps
                 e["orr_w"] += prof.orr * amps
@@ -582,16 +588,19 @@ class ServedActivity:
     @staticmethod
     def _finish(e: dict) -> dict:
         amps = max(e["amps"], 1.0)
+        terms = max(e["z_terms"] + e["fallback_terms"], 1)
         return {"rows": e["rows"], "batches": e["batches"],
                 "amps": e["amps"],
                 "alo": e["alo_w"] / amps, "orr": e["orr_w"] / amps,
                 "ai": e["ai_w"] / amps,
                 "fast_amp_frac": e["fast_w"] / amps,
-                "flops_saved_frac": e["saved_w"] / amps}
+                "flops_saved_frac": e["saved_w"] / amps,
+                "z_pass_term_frac": e["z_terms"] / terms}
 
     def per_plan(self) -> dict[str, dict]:
         """Amps-weighted activity per plan key (rows, amps, ALO, ORR, AI,
-        fast-path and flops-saved fractions)."""
+        fast-path and flops-saved fractions) and the rows-weighted share of
+        observables served by the shared Z-string pass."""
         with self._lock:
             items = {k: dict(v) for k, v in self._per_key.items()}
         return {k: self._finish(e) for k, e in sorted(items.items())}

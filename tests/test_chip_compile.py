@@ -118,6 +118,20 @@ def test_expectation_z(one_chip, qubit):
              STATE, donate=False)
 
 
+def test_z_string_epilogue(one_chip):
+    """The result epilogue's shared Z-string pass for a ring of ZZ terms
+    (the client cell's observables) compiles for the chip at HIGHEST."""
+    from repro.engine import ResultSpec, qaoa_template
+    spec = ResultSpec.expectation([{i: "Z", (i + 1) % N: "Z"}
+                                   for i in range(N)])
+    plan = compile_plan(qaoa_template(N, 1), backend="planar",
+                        target=TPU_V5E, result=spec)
+    epi = plan._epilogue_step(spec)
+    compiled = _compile(one_chip, lambda d: epi(d, None), STATE,
+                        donate=False)
+    assert "operand_precision={highest,highest}" in compiled.as_text()
+
+
 def test_planar_qrc_plan(one_chip):
     plan = compile_plan(template_of(C.qrc(N, depth=8)), backend="planar",
                         target=TPU_V5E)

@@ -8,8 +8,8 @@ checked against the dense gate-by-gate oracle:
   same request is *bitwise identical* under any batch composition (the
   per-request-key PRNG discipline);
 * **expectation** — every served value matches the dense
-  apply-then-inner-product oracle, on all three backends (the pallas
-  backend routes single-qubit-Z through the streaming kernel);
+  apply-then-inner-product oracle, on all three backends (every
+  Z-string of a request shares one pass over |amp|^2);
 * **noisy** — trajectory unraveling averages to the exact density-matrix
   (Kraus-sum) expectation within a statistical bound, and is exact for the
   deterministic channels (p=0, gamma=1).
@@ -383,6 +383,148 @@ def test_expectation_z_kernel_vs_ref_vs_dense(n):
         dense = float(np.sum((np.abs(psi) ** 2) * signs))
         assert abs(kern - ref) < 1e-5
         assert abs(kern - dense) < 1e-5
+
+
+def _z_strings(n, v, seed):
+    """Z-strings of every weight 1..n, plus lane-only, row-only and
+    lane+row ones (qubits below ``v`` are lane qubits)."""
+    rng = np.random.default_rng(seed)
+    picks = [rng.choice(n, w, replace=False) for w in range(1, n + 1)]
+    picks += [[0], range(min(v, n)), [0, n - 1]]
+    if n > v:
+        picks += [[v], range(v, n), [v - 1, n - 1]]
+    return [{int(q): "Z" for q in qs} for qs in picks]
+
+
+@pytest.mark.parametrize("n", [2, 3, 4, 7])
+@pytest.mark.parametrize("backend", ["planar", "pallas", "dense"])
+def test_z_string_pass_matches_oracle_and_per_term(backend, n):
+    """The epilogue's shared Z-string pass == the dense oracle == the
+    per-term apply-then-inner-product path, for strings on lane qubits,
+    row qubits and both, down to states of a single row (n = 2 on a
+    narrowed 4-lane target, n = 3 on the 8-lane one)."""
+    import dataclasses
+    target = (CPU_TEST if n >= 3
+              else dataclasses.replace(CPU_TEST, lanes=4))
+    t = hea_template(n, 1)
+    params = np.random.default_rng(n).uniform(
+        -np.pi, np.pi, t.num_params).astype(np.float32)
+    spec = ResultSpec.expectation(_z_strings(n, target.lane_qubits, n))
+    plan = compile_plan(t, backend=backend, target=target, result=spec)
+    assert (plan.z_pass_terms, plan.fallback_terms) == (
+        len(spec.observables), 0)
+    got = np.asarray(plan.run_result(params))
+    want = [_oracle_expectation(t, params, o) for o in spec.observables]
+    np.testing.assert_allclose(got, want, atol=2e-5)
+    state = plan.run(params)
+    data = state.to_dense() if backend == "dense" else state.data
+    per_term = [float(plan._observable_step(o)(data))
+                for o in spec.observables]
+    np.testing.assert_allclose(got, per_term, atol=2e-5)
+
+
+MIXED_OBS = [{1: "X"}, {0: "Z"}, {0: "Z", 3: "Z"}, {2: "Y", 4: "Z"},
+             {4: "Z"}, {1: "X", 2: "X"}, {1: "Z", 2: "Z", 3: "Z"}]
+
+
+@pytest.mark.parametrize("mode", ["expectation", "noisy"])
+@pytest.mark.parametrize("backend", ["planar", "pallas", "dense"])
+def test_z_string_pass_mixed_spec_keeps_order(backend, mode, t5):
+    """Z-strings between X/Y strings: every row of a vmapped sweep (and
+    each trajectory row of a noisy request, zero-probability channels)
+    returns the spec's order, equal to the oracle term by term."""
+    spec = (ResultSpec.expectation(MIXED_OBS) if mode == "expectation"
+            else ResultSpec.noisy([depolarizing(1, 0.0)], MIXED_OBS,
+                                  unravelings=2, key=5))
+    sched = _make_sched(backend)
+    pm = np.linspace(-1, 1, 3 * t5.num_params).reshape(3, -1)
+    reqs = sched.submit_sweep(t5, pm, result=spec)
+    sched.drain()
+    assert sched.report()["batches"] == 1
+    for r, p in zip(reqs, pm):
+        assert r.ok
+        got = np.asarray(r.result)
+        assert got.shape == (len(MIXED_OBS),) and got.dtype == np.float32
+        np.testing.assert_allclose(
+            got, [_oracle_expectation(t5, p, o) for o in MIXED_OBS],
+            atol=2e-5)
+
+
+def _state_reads(fn, shape):
+    """The top-level primitives of ``fn``'s jaxpr that read its input,
+    and every output shape the jaxpr produces."""
+    jp = jax.make_jaxpr(fn)(jax.ShapeDtypeStruct(shape, jnp.float32))
+    x = jp.jaxpr.invars[0]
+    reads = [e.primitive.name for e in jp.jaxpr.eqns
+             if any(v is x for v in e.invars)]
+    shapes = [v.aval.shape for e in jp.jaxpr.eqns for v in e.outvars]
+    return reads, shapes
+
+
+def test_z_string_pass_reads_state_once():
+    """An all-Z spec's epilogue reads the state in one primitive and takes
+    no flat view of it; an X term adds its own reduction's reads only."""
+    n = 8
+    shape = (2, 1 << (n - CPU_TEST.lane_qubits), CPU_TEST.lanes)
+    zs = [{i: "Z", (i + 1) % n: "Z"} for i in range(4)]
+    x_term = {2: "X"}
+    t = qaoa_template(n, 1)
+
+    def epilogue(obs):
+        spec = ResultSpec.expectation(obs)
+        plan = compile_plan(t, backend="planar", target=CPU_TEST, result=spec)
+        return plan, plan._epilogue_step(spec)
+
+    _, epi = epilogue(zs)
+    reads, shapes = _state_reads(lambda d: epi(d, None), shape)
+    assert len(reads) == 1, reads
+    assert not any((1 << n) in s for s in shapes), shapes
+    plan, epi = epilogue(zs + [x_term])
+    mixed, _ = _state_reads(lambda d: epi(d, None), shape)
+    fallback, _ = _state_reads(
+        plan._observable_step(((2, "X"),)), shape)
+    assert len(mixed) == 1 + len(fallback)
+
+
+def _qaoa_family():
+    import importlib.util
+    import pathlib
+    path = (pathlib.Path(__file__).resolve().parents[1] / "bench"
+            / "families" / "qaoa.py")
+    mod_spec = importlib.util.spec_from_file_location("qaoa_family", path)
+    mod = importlib.util.module_from_spec(mod_spec)
+    mod_spec.loader.exec_module(mod)
+    return mod
+
+
+@pytest.mark.parametrize("case", ["qaoa20_family", "mixed"])
+def test_z_pass_term_frac_counts_routes(case):
+    """``z_pass_term_frac`` is the rows-weighted share of served
+    observables that the shared pass took: all of the client cell's ring
+    of ZZ terms, four of the mixed spec's seven."""
+    from repro.engine import engine_registry
+    if case == "mixed":
+        t, obs, frac = qaoa_template(5, 1), MIXED_OBS, 4 / 7
+    else:
+        fam = _qaoa_family()
+        cfg = {"n": 6, "p": 1}
+        t = fam.program_template(cfg)
+        obs, frac = fam.program_observables(cfg), 1.0
+    sched = _make_sched()
+    pm = np.zeros((3, t.num_params), np.float32)
+    sched.submit_sweep(t, pm, result=ResultSpec.expectation(obs))
+    sched.submit(t, pm[0])                   # statevector: no observables
+    sched.drain()
+    plan = sched.executor.plan_for(t, result=ResultSpec.expectation(obs))
+    assert plan.z_pass_terms + plan.fallback_terms == len(obs)
+    assert plan.z_pass_terms / len(obs) == pytest.approx(frac)
+    act = sched.executor.activity
+    assert act.summary()["z_pass_term_frac"] == pytest.approx(frac)
+    # the statevector rows share the plan label and add no observable
+    assert [a["z_pass_term_frac"] for a in act.per_plan().values()] == \
+        pytest.approx([frac])
+    snap = engine_registry(scheduler=sched).snapshot()
+    assert snap["served_z_pass_term_frac"] == pytest.approx(frac)
 
 
 def test_simulator_expectation_pauli_routes_pallas_kernel():

@@ -50,7 +50,7 @@ def _programs():
                                                  jnp.float32))))
     q = qaoa_template(N, 2)
     spec = ResultSpec.expectation([{i: "Z", (i + 1) % N: "Z"}
-                                   for i in range(N)])
+                                   for i in range(N)] + [{2: "X"}])
     plan = compile_plan(q, backend="planar", target=CPU_TEST, result=spec)
     out.append(("expectation", plan._build_batched_result(),
                 (STATE, jax.ShapeDtypeStruct((4, q.num_params), jnp.float32),
@@ -196,8 +196,9 @@ def test_sharded_swaps_are_tagged_collectives(sharded):
 def test_items_and_epilogue_terms_are_tagged(compiled):
     items = set(re.findall(r'repro_item="(\d+)"', compiled["planar"]))
     assert len(items) > 5
-    terms = set(re.findall(r'repro_term="(\d+)"', compiled["expectation"]))
-    assert terms == {str(i) for i in range(N)}
+    # the N Z-strings share one pass; the X string is term N of the spec
+    terms = set(re.findall(r'repro_term="(\w+)"', compiled["expectation"]))
+    assert terms == {"z", str(N)}
     assert 'repro_kind="epilogue"' in compiled["expectation"]
 
 
